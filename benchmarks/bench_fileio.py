@@ -107,7 +107,10 @@ def _sharded_ckpt():
     and verifies bit-exactness through the range manifest."""
     if _sharded_cache:
         return _sharded_cache["rec"]
+    # a CPU compile by design: the child must never reach for a chip the
+    # parent may hold
     code = ("import os\n"
+            "os.environ['JAX_PLATFORMS'] = 'cpu'\n"
             "os.environ['XLA_FLAGS'] = "
             "'--xla_force_host_platform_device_count=8'\n"
             f"import sys\nsys.path.insert(0, {_SRC!r})\n"
@@ -115,7 +118,7 @@ def _sharded_ckpt():
         import json, tempfile, shutil, time
         import numpy as np
         import jax
-        from jax.sharding import Mesh
+        from repro.launch.mesh import make_mesh
         from repro import ckpt
         from repro.dist.sharding import ShardCtx, param_shardings
 
@@ -126,16 +129,14 @@ def _sharded_ckpt():
             "embedding": rng.normal(size=(128, 64)).astype(np.float32)}}
         shapes = jax.tree_util.tree_map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
-        mesh8 = Mesh(np.array(jax.devices()).reshape(2, 4),
-                     ("data", "model"))
+        mesh8 = make_mesh((2, 4), ("data", "model"))
         sh8 = param_shardings(shapes, ShardCtx(mesh=mesh8))
         dev = jax.tree_util.tree_map(jax.device_put, tree, sh8)
         tmp = tempfile.mkdtemp()
         t0 = time.perf_counter()
         st = ckpt.save(tmp, dev, 1, num_writers=8)
         wall_ms = (time.perf_counter() - t0) * 1e3
-        mesh2 = Mesh(np.array(jax.devices()[:2]).reshape(1, 2),
-                     ("data", "model"))
+        mesh2 = make_mesh((1, 2), ("data", "model"), devices=jax.devices()[:2])
         sh2 = param_shardings(shapes, ShardCtx(mesh=mesh2))
         got, _ = ckpt.restore(tmp, shardings=sh2)
         exact = all(

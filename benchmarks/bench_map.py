@@ -58,7 +58,10 @@ def _sharded_step(arch: str = "smollm-360m", steps: int = 3):
     """
     if arch in _sharded_cache:
         return _sharded_cache[arch]
+    # a CPU compile by design: the child must never reach for a chip the
+    # parent may hold
     code = ("import os\n"
+            "os.environ['JAX_PLATFORMS'] = 'cpu'\n"
             "os.environ['XLA_FLAGS'] = "
             "'--xla_force_host_platform_device_count=8'\n"
             f"import sys\nsys.path.insert(0, {_SRC!r})\n"
@@ -77,7 +80,8 @@ def _sharded_step(arch: str = "smollm-360m", steps: int = 3):
         oc = OptimizerConfig()
         data = SyntheticTokens(cfg.vocab_size, batch=8, seq=32, seed=0)
         state = init_train_state(model, jax.random.PRNGKey(0), oc)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         b = {{k: jnp.asarray(v) for k, v in data.get(0).items()}}
         with use_mesh(mesh):
             fn = jax.jit(make_train_step(model, oc))

@@ -34,7 +34,10 @@ def _cell(num_experts: int, dispatch: str, steps: int = 5):
     key = (num_experts, dispatch)
     if key in _cache:
         return _cache[key]
+    # a CPU compile by design: the child must never reach for a chip the
+    # parent may hold
     code = ("import os\n"
+            "os.environ['JAX_PLATFORMS'] = 'cpu'\n"
             "os.environ['XLA_FLAGS'] = "
             "'--xla_force_host_platform_device_count=8'\n"
             f"import sys\nsys.path.insert(0, {_SRC!r})\n"
@@ -58,7 +61,8 @@ def _cell(num_experts: int, dispatch: str, steps: int = 5):
             y, aux = M.moe_ffn(p, xx, cfg)
             return jnp.sum(y ** 2) + 0.01 * aux["loss"], aux
 
-        mesh = jax.make_mesh((1, 8), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((1, 8), ("data", "model"))
         with use_mesh(mesh):
             fn = jax.jit(jax.value_and_grad(loss, has_aux=True))
             lowered = fn.lower(params, x)

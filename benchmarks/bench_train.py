@@ -34,7 +34,10 @@ def _dryrun_cell(arch: str):
     (1, 16) mesh and parse collective traffic from the SPMD HLO."""
     if arch in _dryrun_cache:
         return _dryrun_cache[arch]
+    # a CPU compile by design: the child must never reach for a chip the
+    # parent may hold
     code = ("import os\n"
+            "os.environ['JAX_PLATFORMS'] = 'cpu'\n"
             "os.environ['XLA_FLAGS'] = "
             "'--xla_force_host_platform_device_count=16'\n"
             f"import sys\nsys.path.insert(0, {_SRC!r})\n"
@@ -49,7 +52,8 @@ def _dryrun_cell(arch: str):
 
         cfg = get_config("{arch}")
         shape = shape_by_name("train_4k")
-        mesh = jax.make_mesh((1, 16), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((1, 16), ("data", "model"))
         t0 = time.time()
         with use_mesh(mesh) as ctx:
             lowered, _ = lower_cell(cfg, shape, mesh, ctx)

@@ -43,19 +43,21 @@ def main() -> None:
         sections = tuple(s if s in _SECTIONS else f"bench_{s}"
                          for s in wanted)
 
-    mods = []
+    mods, failed = [], []
     print("name,us_per_call,derived")
     for name in sections:
-        # a section with missing deps (e.g. an optional subsystem) reports
-        # and is skipped instead of killing the whole driver
+        # a section that fails to import or run is reported, the others
+        # still run, and the exit status is non-zero at the end
         try:
             mod = importlib.import_module(f"benchmarks.{name}")
+            for row_name, us, derived in mod.run():
+                print(f"{row_name},{us},{derived}")
         except Exception as e:
-            print(f"{name}.SKIP,0,import_error={type(e).__name__}: {e}")
+            print(f"# {name} FAILED: {type(e).__name__}: {e}",
+                  file=sys.stderr)
+            failed.append(name)
             continue
         mods.append(mod)
-        for row_name, us, derived in mod.run():
-            print(f"{row_name},{us},{derived}")
 
     out_dir = os.environ.get("BENCH_JSON_DIR", ".")
     for mod in mods:
@@ -68,6 +70,8 @@ def main() -> None:
             json.dump(summary(), f, indent=2, sort_keys=True)
             f.write("\n")
         print(f"# wrote {path}", file=sys.stderr)
+    if failed:
+        sys.exit(f"benchmark sections failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -41,6 +41,7 @@ from __future__ import annotations
 
 import dataclasses
 import heapq
+import itertools
 from typing import Dict, List, Optional, Tuple, TYPE_CHECKING
 
 if TYPE_CHECKING:                                       # pragma: no cover
@@ -85,7 +86,7 @@ class IoQueue:
         self._flush_scheduled = False
         # elevator pass: submitted write ops whose disk slot hasn't started
         # yet, indexed by (node, path) — later flushes merge into them
-        self._pending_writes: Dict[Tuple[int, str], List[IoOp]] = {}
+        self._pending_writes: Dict[Tuple[int, str], Dict[int, IoOp]] = {}
         self.inflight = 0                 # ops submitted, completion not seen
         self.reads_inflight = 0
         # monitoring only (rt._mon is not None): per-node start times of
@@ -118,7 +119,7 @@ class IoQueue:
         self.rt.send(MIoDone(op=op), op.node, op.node, at=done)
         if op.kind == "write" and not op.performed:
             self._pending_writes.setdefault((op.node, op.path),
-                                            []).append(op)
+                                            {})[id(op)] = op
         if self.rt._mon is not None:
             # publish the io.* gauges live at submit (not at run() return)
             self._queued_starts.setdefault(op.node, []).append(op.start)
@@ -135,8 +136,7 @@ class IoQueue:
         elif op.kind == "write":
             pend = self._pending_writes.get((op.node, op.path))
             if pend is not None:
-                if op in pend:
-                    pend.remove(op)
+                pend.pop(id(op), None)
                 if not pend:
                     del self._pending_writes[(op.node, op.path)]
         if self.rt._mon is not None:
@@ -225,7 +225,7 @@ class IoQueue:
                            (self.rt.clock if at is None else at,
                             next(self.rt._tick), "io_flush", None))
 
-    def _elevator_merge(self, op: IoOp) -> bool:
+    def _elevator_merge(self, op: IoOp, scan: Optional[int] = None) -> bool:
         """Absorb ``op`` into a queued-but-unstarted write of the same
         (node, file) when the ranges are adjacent (ROADMAP
         "cross-timestamp write coalescing").
@@ -242,9 +242,13 @@ class IoQueue:
         newest payload must land *last*, so ``op`` takes a fresh disk
         slot (FIFO per node puts it behind every queued op) instead of
         riding an earlier one.
+
+        ``scan`` limits both checks to the oldest ``scan`` pending writes:
+        the ones queued before the current flush.
         """
         now = self.rt.clock
-        pend = self._pending_writes.get((op.node, op.path), ())
+        pend = list(itertools.islice(
+            self._pending_writes.get((op.node, op.path), {}).values(), scan))
         for prior in pend:
             if prior.offset < op.offset + op.size and \
                     op.offset < prior.offset + prior.size:
@@ -282,21 +286,36 @@ class IoQueue:
         groups: Dict[Tuple[int, str], List[IoOp]] = {}
         for op in buf:
             groups.setdefault((op.node, op.path), []).append(op)
-        for (_node, _path), ops in sorted(groups.items()):
+        for key, ops in sorted(groups.items()):
             ops.sort(key=lambda o: o.offset)
-            merged = ops[0]
+            # the runs of one flush neither overlap nor touch, so the
+            # elevator needs to look only at writes queued before it —
+            # unless a chunk is written twice in this flush, when the
+            # overlap check must see the earlier write too
+            disjoint = all(a.offset + a.size <= b.offset
+                           for a, b in zip(ops, ops[1:]))
+            scan = len(self._pending_writes.get(key, ())) if disjoint \
+                else None
+            merged, parts = ops[0], [ops[0].data or b""]
             for op in ops[1:]:
                 if op.offset == merged.offset + merged.size:
-                    merged.data = (merged.data or b"") + (op.data or b"")
+                    # payloads join once per run: concatenating as we go
+                    # copies the growing run per chunk (quadratic)
+                    parts.append(op.data or b"")
                     merged.size += op.size
                     merged.chunks += op.chunks
                     self.rt.stats.io_coalesced_writes += op.chunks
                 else:
-                    if not self._elevator_merge(merged):
-                        self._submit(merged, self.rt.clock)
-                    merged = op
-            if not self._elevator_merge(merged):
-                self._submit(merged, self.rt.clock)
+                    self._flush_run(merged, parts, scan)
+                    merged, parts = op, [op.data or b""]
+            self._flush_run(merged, parts, scan)
+
+    def _flush_run(self, merged: IoOp, parts: List[bytes],
+                   scan: Optional[int]) -> None:
+        if len(parts) > 1:
+            merged.data = b"".join(parts)
+        if not self._elevator_merge(merged, scan):
+            self._submit(merged, self.rt.clock)
 
     # ---------------------------------------------------------- sync mode
 

@@ -9,6 +9,7 @@ two tasks in RW on the whole parent serialize.
 """
 from __future__ import annotations
 
+import bisect
 import dataclasses
 from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
 
@@ -444,16 +445,32 @@ class FileObj:
     chunks: Dict[Guid, Tuple[int, int]] = dataclasses.field(default_factory=dict)
     released: bool = False
     closed: bool = False
+    # live non-empty chunks as sorted, disjoint (start, end) pairs: the
+    # overlap check bisects instead of scanning every chunk of the file
+    _spans: List[Tuple[int, int]] = dataclasses.field(default_factory=list)
 
     @property
     def writable(self) -> bool:
         return "+" in self.mode or self.mode.startswith("w")
 
     def chunk_overlaps(self, offset: int, size: int) -> bool:
-        for (o, s) in self.chunks.values():
-            if offset < o + s and o < offset + size:
-                return True
-        return False
+        if size <= 0:
+            return False
+        # disjoint spans sorted by start have sorted ends, so only the last
+        # span starting before this range's end can reach into it
+        i = bisect.bisect_left(self._spans, (offset + size,))
+        return i > 0 and self._spans[i - 1][1] > offset
+
+    def add_chunk(self, g: Guid, offset: int, size: int) -> None:
+        self.chunks[g] = (offset, size)
+        if size > 0:
+            bisect.insort(self._spans, (offset, offset + size))
+
+    def remove_chunk(self, g: Guid) -> None:
+        span = self.chunks.pop(g, None)
+        if span is not None and span[1] > 0:
+            del self._spans[bisect.bisect_left(
+                self._spans, (span[0], span[0] + span[1]))]
 
 
 @dataclasses.dataclass

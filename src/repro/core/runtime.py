@@ -1539,7 +1539,7 @@ class Runtime:
                     self.stats.file_bytes_written += db.size
             elif f.writable and db.file_offset + db.size > _file_size(f.path):
                 _enlarge_file(f.path, db.file_offset + db.size)
-            f.chunks.pop(db.guid, None)
+            f.remove_chunk(db.guid)
             if f.released and not f.chunks:
                 f.closed = True
         db.destroyed = True
@@ -1693,11 +1693,12 @@ class Runtime:
                     ranges: List[Tuple[int, int, int]]) -> bool:
         """Route a multi-range copy through the fused Pallas kernel.
 
-        Returns False (caller falls back to numpy) unless the backend is
+        Returns False (caller copies through numpy) unless the backend is
         enabled, the batch is big enough to amortize a launch, every range
-        is lane-aligned (128 B) and non-empty, destinations are disjoint
-        (overlaps need the sequential last-writer-wins semantics of the
-        numpy path), and jax is importable.
+        is lane-aligned (128 B) and non-empty, and destinations are
+        disjoint (overlaps need the sequential last-writer-wins semantics
+        of the numpy path).  A kernel that fails to import or run raises:
+        ``copy_backend="pallas"`` never degrades to numpy in silence.
         """
         if self.copy_backend != "pallas" or len(ranges) < 2:
             return False
@@ -1705,10 +1706,7 @@ class Runtime:
             return False
         if spans_overlap((d, d + n) for d, _, n in ranges):
             return False
-        try:
-            from ..kernels import ops
-        except Exception:       # jax unavailable: gate, don't require it
-            return False
+        from ..kernels import ops
         out = ops.multi_partition_copy_bytes(dbuf, sbuf, tuple(ranges))
         dbuf[:] = np.asarray(out)
         self.stats.fused_copies += 1
@@ -2261,7 +2259,7 @@ class TaskCtx:
         db.ready = True
         db.pending_deps = []
         self.rt.nodes[self.node].objects.insert(db)
-        f.chunks[g] = (offset, size)
+        f.add_chunk(g, offset, size)
         if db.lazy_file_read and self.rt.io_mode == "async" \
                 and self.rt.read_ahead:
             # §5 read-ahead: the fetch streams on the node's IO queue from

@@ -36,7 +36,7 @@ from jax.sharding import PartitionSpec as P
 from repro.kernels import ops as kernel_ops
 from repro.models.attention import (decode_attention, flash_min_seq,
                                     full_attention)
-from .sharding import current_ctx, shard_map
+from .sharding import current_ctx
 
 NEG_INF = -1e30
 
@@ -94,8 +94,9 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *, cfg=None,
             return _attn_local(ql, kl, vl, window=window,
                                block_q=bq, block_k=bk, min_seq=min_seq)
 
-        return shard_map(inner, ctx.mesh, in_specs=(spec, spec, spec),
-                         out_specs=spec)(q, k, v)
+        return jax.shard_map(inner, mesh=ctx.mesh,
+                             in_specs=(spec, spec, spec), out_specs=spec,
+                             check_vma=False)(q, k, v)
 
     if s % m == 0:
         # context-parallel: q stripes over "model", k/v whole; q_offset
@@ -110,8 +111,9 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *, cfg=None,
             return _attn_local(ql, kl, vl, window=window, block_q=bq,
                                block_k=bk, min_seq=min_seq, q_offset=off)
 
-        return shard_map(inner, ctx.mesh, in_specs=(qspec, kvspec, kvspec),
-                         out_specs=qspec)(q, k, v)
+        return jax.shard_map(inner, mesh=ctx.mesh,
+                             in_specs=(qspec, kvspec, kvspec),
+                             out_specs=qspec, check_vma=False)(q, k, v)
 
     return _attn_local(q, k, v, window=window, block_q=bq, block_k=bk,
                        min_seq=min_seq)
@@ -126,13 +128,18 @@ def _decode_local(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     q: (B, 1, H, hd); caches: (B, KH, S, hd); valid: scalar int32 count of
     valid cache entries.  Routes to the Pallas flash-decode kernel on a
     TPU backend (decode is never differentiated), jnp oracle elsewhere.
+    A cache length off the kernel's 128-entry tiling is zero-padded: the
+    padding lies past ``valid`` and is masked like any unwritten entry.
     """
     b, _, h, hd = q.shape
     kh = k_cache.shape[1]
     g = h // kh
     smax = k_cache.shape[2]
-    if jax.default_backend() == "tpu" and smax % 128 == 0:
+    if jax.default_backend() == "tpu":
         from repro.kernels.flash_decode import flash_decode
+        pad = ((0, 0), (0, 0), (0, (-smax) % 128), (0, 0))
+        if smax % 128:
+            k_cache, v_cache = jnp.pad(k_cache, pad), jnp.pad(v_cache, pad)
         qg = q[:, 0].reshape(b, kh, g, hd)
         out = flash_decode(qg, k_cache, v_cache, valid, window=window)
         return out.reshape(b, 1, h, v_cache.shape[-1])
@@ -175,9 +182,10 @@ def decode_update_and_attend(q: jax.Array, k_new: jax.Array,
         def inner(c, ql, kcl, vcl):
             return _decode_local(ql, kcl, vcl, c + 1, window)
 
-        out = shard_map(inner, ctx.mesh,
-                        in_specs=(P(), qspec, cspec, cspec),
-                        out_specs=qspec)(cur, q, k_cache, v_cache)
+        out = jax.shard_map(inner, mesh=ctx.mesh,
+                            in_specs=(P(), qspec, cspec, cspec),
+                            out_specs=qspec,
+                            check_vma=False)(cur, q, k_cache, v_cache)
         return out, k_cache, v_cache
 
     if smax % m == 0:
@@ -210,9 +218,10 @@ def decode_update_and_attend(q: jax.Array, k_new: jax.Array,
             out = num / jnp.maximum(den, 1e-37)[..., None]
             return out.reshape(bl, 1, h, -1).astype(ql.dtype)
 
-        out = shard_map(inner, ctx.mesh,
-                        in_specs=(P(), qspec, cspec, cspec),
-                        out_specs=qspec)(cur, q, k_cache, v_cache)
+        out = jax.shard_map(inner, mesh=ctx.mesh,
+                            in_specs=(P(), qspec, cspec, cspec),
+                            out_specs=qspec,
+                            check_vma=False)(cur, q, k_cache, v_cache)
         return out, k_cache, v_cache
 
     out = _decode_local(q, k_cache, v_cache, cur + 1, window)
@@ -322,9 +331,10 @@ def mla_decode_attend(q_latent: jax.Array, q_rope: jax.Array,
         def inner(ql, qr, ckv, kr, c):
             return attend(ql, qr, ckv, kr, c)
 
-        out = shard_map(inner, ctx.mesh,
-                        in_specs=(qspec, qspec, cspec, cspec, P()),
-                        out_specs=qspec)(q_latent, q_rope, c_kv, k_rope, cur)
+        out = jax.shard_map(inner, mesh=ctx.mesh,
+                            in_specs=(qspec, qspec, cspec, cspec, P()),
+                            out_specs=qspec, check_vma=False)(
+                                q_latent, q_rope, c_kv, k_rope, cur)
         return out, c_kv, k_rope
 
     out = attend(q_latent, q_rope, c_kv, k_rope, cur)

@@ -49,23 +49,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 Axes = Union[None, str, Tuple[str, ...]]
 
 
-def shard_map(f, mesh, in_specs, out_specs, check: bool = False):
-    """Version-portable ``shard_map`` (0.4.x experimental → 0.5+ jax.*).
-
-    ``check`` maps onto ``check_vma`` (new) / ``check_rep`` (old).
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check)
-        except TypeError:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as sm_exp
-    return sm_exp(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check)
-
-
 # --------------------------------------------------------------------- context
 
 @dataclasses.dataclass(frozen=True)

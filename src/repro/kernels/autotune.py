@@ -95,6 +95,23 @@ ELEM_COST = {"interpret": 390.0, "interpret_flat": 340.0, "tpu": 2.0,
 # and the gate only bounds the materialized (B·KH·G·S·S) softmax
 # transients.
 MEGA_BUDGET = {"interpret": 192 * 2 ** 20, "tpu": None}
+# What Mosaic holds in VMEM beyond the pipelined blocks, per grid kernel:
+# f32 score-sized (gf·bq, bk) tiles the body keeps live at once (s, p, the
+# mask, dp, ds) and f32 (gf·bq, 1) row columns (m/l scratch, lse/delta
+# blocks, double-buffered).  On TPU a minor dim is padded to the 128-lane
+# width, so a column costs 128 floats per row.  Counts fitted to the v5e
+# compiler, which refuses the fused backward at gf·bq=1536, bk=512 and the
+# forward at 3072×1024.  Interpret-mode transients are host arrays that
+# its budget does not bound.
+TILE_TRANSIENTS = {"tpu": {"fwd": (4, 4), "dq": (5, 4), "dkv": (5, 4),
+                           "fused": (6, 4)}}
+LANE_WIDTH = {"tpu": 128}
+
+
+def _transients(backend: str, kernel: str, rows: int, bk: int) -> int:
+    tiles, cols = TILE_TRANSIENTS.get(backend, {}).get(kernel, (0, 0))
+    lanes = LANE_WIDTH.get(backend, 1)
+    return (tiles * max(bk, lanes) + cols * lanes) * rows * 4
 
 
 def vmem_budget_bytes(backend: str = "tpu") -> int:
@@ -312,6 +329,8 @@ def plan_attention(sq: int, sk: int, hd: int, hd_v: int, g: int, kh: int,
     block_cap = GRID_BLOCK_CAP.get(backend, MAX_BLOCK)
     in_bytes = max(dtype_bits // 8, 1)
     hd_work = hd + hd_v
+    lanes = LANE_WIDTH.get(backend, 1)
+    hd_l, hd_vl = max(hd, lanes), max(hd_v, lanes)   # VMEM footprint dims
 
     # Overrides pin their axis verbatim (clamped to the sequence, the
     # historical ``min(block, seq)`` behavior); the other axis is still
@@ -327,6 +346,9 @@ def plan_attention(sq: int, sk: int, hd: int, hd_v: int, g: int, kh: int,
     else:
         hi_k = min(block_cap, _ceil_div(sk, MIN_BLOCK) * MIN_BLOCK)
         k_cands = list(_pow2s(MIN_BLOCK, hi_k)) or [MIN_BLOCK]
+        # bk is the score tile's lane dim: narrower tiles pad to the lane
+        # width anyway, so they only add grid steps
+        k_cands = [c for c in k_cands if c >= lanes] or k_cands
     pinned = block_q is not None or block_k is not None
 
     gf_cands = _divisors(g)
@@ -341,7 +363,8 @@ def plan_attention(sq: int, sk: int, hd: int, hd_v: int, g: int, kh: int,
     for bq in q_cands:
         for bk in k_cands:
             for gf in gf_cands:
-                vm = _fwd_vmem(bq, bk, gf, hd, hd_v, in_bytes)
+                vm = _fwd_vmem(bq, bk, gf, hd_l, hd_vl, in_bytes) \
+                    + _transients(backend, "fwd", gf * bq, bk)
                 if vm > budget and not (pinned and gf == 1):
                     continue
                 c = _pass_cost(sq, sk, bq, bk, gf, g, kh, batch, hd_work,
@@ -363,7 +386,7 @@ def plan_attention(sq: int, sk: int, hd: int, hd_v: int, g: int, kh: int,
         bwd_q_cands = [b for b in _pow2s(MIN_BLOCK, min(block_cap, sq_p))
                        if sq_p % b == 0] or [bq]
         bwd_k_cands = [b for b in _pow2s(MIN_BLOCK, min(block_cap, sk_p))
-                       if sk_p % b == 0] or [bk]
+                       if sk_p % b == 0 and b >= min(lanes, bk)] or [bk]
 
     # q/do/dq + lse/delta, k/v in; dk/dv whole-kv RMW counts twice
     pb_fused = batch * kh * (g * sq_p * (2 * hd + hd_v + 2)
@@ -378,7 +401,8 @@ def plan_attention(sq: int, sk: int, hd: int, hd_v: int, g: int, kh: int,
     best_fused = None
     for fbq in bwd_q_cands:
         for fbk in bwd_k_cands:
-            vm = _fused_vmem(fbq, fbk, g, sk_p, hd, hd_v, in_bytes)
+            vm = _fused_vmem(fbq, fbk, g, sk_p, hd_l, hd_vl, in_bytes) \
+                + _transients(backend, "fused", g * fbq, fbk)
             if vm > budget:
                 continue
             c = _pass_cost(sq_p, sk_p, fbq, fbk, g, g, kh, batch,
@@ -394,7 +418,8 @@ def plan_attention(sq: int, sk: int, hd: int, hd_v: int, g: int, kh: int,
     for dbq in bwd_q_cands:
         for dbk in bwd_k_cands:
             for dgf in gf_cands:
-                vm = _dq_vmem(dbq, dbk, dgf, hd, hd_v, in_bytes)
+                vm = _dq_vmem(dbq, dbk, dgf, hd_l, hd_vl, in_bytes) \
+                    + _transients(backend, "dq", dgf * dbq, dbk)
                 if vm > budget and not (pinned and dgf == 1):
                     continue
                 c = _pass_cost(sq_p, sk_p, dbq, dbk, dgf, g, kh, batch,
@@ -408,7 +433,8 @@ def plan_attention(sq: int, sk: int, hd: int, hd_v: int, g: int, kh: int,
     for dbq in bwd_q_cands:
         for dbk in bwd_k_cands:
             for dgf in gf_cands:
-                vm = _dkv_vmem(dbq, dbk, dgf, hd, hd_v, in_bytes)
+                vm = _dkv_vmem(dbq, dbk, dgf, hd_l, hd_vl, in_bytes) \
+                    + _transients(backend, "dkv", dgf * dbq, dbk)
                 if vm > budget and not (pinned and dgf == 1):
                     continue
                 c = _pass_cost(sq_p, sk_p, dbq, dbk, dgf, g, kh, batch,

@@ -33,6 +33,9 @@ block-level ``pl.when`` skips stay globally positioned in both directions.
 Layouts (chosen for MXU alignment):
   q:    (B, H, S, hd) public → (B, KH, G, S, hd) internal
   k, v: (B, KH, S, hd)
+  lse, delta: (B, KH, G, S, 1) — one value per row, kept on sublanes so a
+  (g, bq, 1) block merges into the (g·bq, 1) column the tiles broadcast
+  against without a lane→sublane relayout (Mosaic refuses that cast).
 Causal tiles with j·bk > (i+1)·bq are skipped with ``pl.when`` — no wasted
 MXU work, unlike the masked jnp oracle.  Sequence lengths that do not
 divide the block sizes are zero-padded at the edge and masked via the
@@ -51,11 +54,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import autotune
 from repro.kernels.autotune import AttnPlan
-
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4.x/0.5.x;
-# resolve whichever this jax provides
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
 
 NEG_INF = -1e30
 
@@ -181,7 +179,7 @@ def _fwd_kernel(off_ref, q_ref, k_ref, v_ref, *rest,
         o = _dot(p, v, ((1,), (0,))) / l
         o_ref[0, 0] = o.reshape(gf, block_q, hd_v).astype(o_ref.dtype)
         if with_lse:
-            lse_ref[0, 0] = (m + jnp.log(l)).reshape(gf, block_q)
+            lse_ref[0, 0] = (m + jnp.log(l)).reshape(gf, block_q, 1)
         return
 
     @pl.when(j == 0)
@@ -213,7 +211,8 @@ def _fwd_kernel(off_ref, q_ref, k_ref, v_ref, *rest,
         o_ref[0, 0] = (acc_ref[...] / l).reshape(
             gf, block_q, hd_v).astype(o_ref.dtype)
         if with_lse:
-            lse_ref[0, 0] = (m_ref[...] + jnp.log(l)).reshape(gf, block_q)
+            lse_ref[0, 0] = (m_ref[...] + jnp.log(l)).reshape(
+                gf, block_q, 1)
 
 
 # ---- megakernels: grid (1,), whole arrays as blocks, one batched dot
@@ -235,8 +234,9 @@ def _mega_amask(off_ref, g: int, sq: int, sk: int, causal: bool,
 
 
 def _bdot(a, b, contract):
-    """dot_general batched over the leading (B, KH) dims."""
-    return jax.lax.dot_general(a, b, (contract, ((0, 1), (0, 1))),
+    """dot_general batched over the leading dim — the (B, KH) slices merged
+    into one, since Mosaic's matmul takes a single batch dimension."""
+    return jax.lax.dot_general(a, b, (contract, ((0,), (0,))),
                                preferred_element_type=jnp.float32)
 
 
@@ -249,19 +249,20 @@ def _fwd_mega_kernel(off_ref, q_ref, k_ref, v_ref, *rest, g: int,
     sk = k_ref.shape[2]
     hd_v = v_ref.shape[-1]
     amask = _mega_amask(off_ref, g, sq, sk, causal, window, kv_len)
-    q = q_ref[...].reshape(b, kh, g * sq, hd).astype(jnp.float32) * scale
-    kt = k_ref[...].astype(jnp.float32)                # (b, kh, sk, hd)
-    vt = v_ref[...].astype(jnp.float32)
-    s = _bdot(q, kt, ((3,), (3,)))                     # (b, kh, g·sq, sk)
+    bh = b * kh
+    q = q_ref[...].reshape(bh, g * sq, hd).astype(jnp.float32) * scale
+    kt = k_ref[...].reshape(bh, sk, hd).astype(jnp.float32)
+    vt = v_ref[...].reshape(bh, sk, hd_v).astype(jnp.float32)
+    s = _bdot(q, kt, ((2,), (2,)))                     # (b·kh, g·sq, sk)
     if amask is not None:
         s = s + amask
     m = s.max(axis=-1, keepdims=True)
     p = jnp.exp(s - m)
     l = jnp.maximum(p.sum(axis=-1, keepdims=True), 1e-37)
-    o = _bdot(p, vt, ((3,), (2,))) / l
+    o = _bdot(p, vt, ((2,), (1,))) / l
     o_ref[...] = o.reshape(b, kh, g, sq, hd_v).astype(o_ref.dtype)
     if with_lse:
-        lse_ref[...] = (m + jnp.log(l)).reshape(b, kh, g, sq)
+        lse_ref[...] = (m + jnp.log(l)).reshape(b, kh, g, sq, 1)
 
 
 def _whole(shape):
@@ -290,8 +291,9 @@ def _fwd_mega_call(q, k, v, offs, *, causal: bool, window: int,
     out_shape = [jax.ShapeDtypeStruct((b, kh, g, sq, hd_v), q.dtype)]
     out_specs = [spec((b, kh, g, sq, hd_v))]
     if with_lse:
-        out_shape.append(jax.ShapeDtypeStruct((b, kh, g, sq), jnp.float32))
-        out_specs.append(spec((b, kh, g, sq)))
+        out_shape.append(jax.ShapeDtypeStruct((b, kh, g, sq, 1),
+                                              jnp.float32))
+        out_specs.append(spec((b, kh, g, sq, 1)))
     res = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -301,7 +303,7 @@ def _fwd_mega_call(q, k, v, offs, *, causal: bool, window: int,
             out_specs=out_specs,
         ),
         out_shape=out_shape,
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
                 ("parallel",) if batch_tiled else ("arbitrary",))),
         interpret=interpret,
@@ -316,25 +318,26 @@ def _bwd_mega_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     sk = k_ref.shape[2]
     hd_v = v_ref.shape[-1]
     amask = _mega_amask(off_ref, g, sq, sk, causal, window, kv_len)
-    q = q_ref[...].reshape(b, kh, g * sq, hd).astype(jnp.float32)
-    kt = k_ref[...].astype(jnp.float32)                # (b, kh, sk, hd)
-    vt = v_ref[...].astype(jnp.float32)
-    do = do_ref[...].reshape(b, kh, g * sq, hd_v).astype(jnp.float32)
-    lse = lse_ref[...].reshape(b, kh, g * sq, 1)
-    delta = delta_ref[...].reshape(b, kh, g * sq, 1)
-    s = _bdot(q * scale, kt, ((3,), (3,)))
+    bh = b * kh
+    q = q_ref[...].reshape(bh, g * sq, hd).astype(jnp.float32)
+    kt = k_ref[...].reshape(bh, sk, hd).astype(jnp.float32)
+    vt = v_ref[...].reshape(bh, sk, hd_v).astype(jnp.float32)
+    do = do_ref[...].reshape(bh, g * sq, hd_v).astype(jnp.float32)
+    lse = lse_ref[...].reshape(bh, g * sq, 1)
+    delta = delta_ref[...].reshape(bh, g * sq, 1)
+    s = _bdot(q * scale, kt, ((2,), (2,)))
     if amask is not None:
         s = s + amask
-    p = jnp.exp(s - lse)                               # (b, kh, g·sq, sk)
+    p = jnp.exp(s - lse)                               # (b·kh, g·sq, sk)
     # contraction over the g·sq rows IS the GQA group sum
-    dv = _bdot(p, do, ((2,), (2,)))                    # (b, kh, sk, hd_v)
-    dp = _bdot(do, vt, ((3,), (3,)))
+    dv = _bdot(p, do, ((1,), (1,)))                    # (b·kh, sk, hd_v)
+    dp = _bdot(do, vt, ((2,), (2,)))
     ds = p * (dp - delta) * scale
-    dq = _bdot(ds, kt, ((3,), (2,)))
-    dk = _bdot(ds, q, ((2,), (2,)))
+    dq = _bdot(ds, kt, ((2,), (1,)))
+    dk = _bdot(ds, q, ((1,), (1,)))
     dq_ref[...] = dq.reshape(b, kh, g, sq, hd).astype(dq_ref.dtype)
-    dk_ref[...] = dk.astype(dk_ref.dtype)
-    dv_ref[...] = dv.astype(dv_ref.dtype)
+    dk_ref[...] = dk.reshape(b, kh, sk, hd).astype(dk_ref.dtype)
+    dv_ref[...] = dv.reshape(b, kh, sk, hd_v).astype(dv_ref.dtype)
 
 
 def _bwd_mega_call(q, k, v, do, lse, delta, offs, *, causal: bool,
@@ -364,7 +367,7 @@ def _bwd_mega_call(q, k, v, do, lse, delta, offs, *, causal: bool,
             jax.ShapeDtypeStruct((b, kh, sk, hd), k.dtype),
             jax.ShapeDtypeStruct((b, kh, sk, hd_v), v.dtype),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
                 ("parallel",) if batch_tiled else ("arbitrary",))),
         interpret=interpret,
@@ -400,10 +403,11 @@ def _fwd_call(q, k, v, offs, *, causal: bool, window: int, plan: AttnPlan,
         (1, 1, gf, block_q, hd_v),
         lambda bb, hh, ii, jj, off: (bb, hh // ngf, hh % ngf, ii, 0))]
     if with_lse:
-        out_shape.append(jax.ShapeDtypeStruct((b, kh, g, sq), jnp.float32))
+        out_shape.append(jax.ShapeDtypeStruct((b, kh, g, sq, 1),
+                                              jnp.float32))
         out_specs.append(pl.BlockSpec(
-            (1, 1, gf, block_q),
-            lambda bb, hh, ii, jj, off: (bb, hh // ngf, hh % ngf, ii)))
+            (1, 1, gf, block_q, 1),
+            lambda bb, hh, ii, jj, off: (bb, hh // ngf, hh % ngf, ii, 0)))
 
     scratch = []
     if nk > 1:
@@ -438,7 +442,7 @@ def _fwd_call(q, k, v, offs, *, causal: bool, window: int, plan: AttnPlan,
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -653,12 +657,12 @@ def _bwd_call(q, k, v, do, lse, delta, offs, plan: AttnPlan, *,
             pl.BlockSpec((1, 1, g, bq, hd_v),
                          lambda bb, hk, ii, jj, off:
                          (bb, hk, 0, ii, 0)),
-            pl.BlockSpec((1, 1, g, bq),
+            pl.BlockSpec((1, 1, g, bq, 1),
                          lambda bb, hk, ii, jj, off:
-                         (bb, hk, 0, ii)),
-            pl.BlockSpec((1, 1, g, bq),
+                         (bb, hk, 0, ii, 0)),
+            pl.BlockSpec((1, 1, g, bq, 1),
                          lambda bb, hk, ii, jj, off:
-                         (bb, hk, 0, ii)),
+                         (bb, hk, 0, ii, 0)),
         ]
         operands = [offs, q, k, v, do, lse, delta]
         if premask:
@@ -693,7 +697,7 @@ def _bwd_call(q, k, v, do, lse, delta, offs, plan: AttnPlan, *,
                 jax.ShapeDtypeStruct((b, kh, sk, hd), jnp.float32),
                 jax.ShapeDtypeStruct((b, kh, sk, hd_v), jnp.float32),
             ],
-            compiler_params=_COMPILER_PARAMS(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary",
                                      "arbitrary")),
             interpret=interpret,
@@ -728,12 +732,12 @@ def _bwd_call(q, k, v, do, lse, delta, offs, plan: AttnPlan, *,
                 pl.BlockSpec((1, 1, gf, bq, hd_v),
                              lambda bb, hh, ii, jj, off:
                              (bb, hh // ngf, hh % ngf, ii, 0)),
-                pl.BlockSpec((1, 1, gf, bq),
+                pl.BlockSpec((1, 1, gf, bq, 1),
                              lambda bb, hh, ii, jj, off:
-                             (bb, hh // ngf, hh % ngf, ii)),
-                pl.BlockSpec((1, 1, gf, bq),
+                             (bb, hh // ngf, hh % ngf, ii, 0)),
+                pl.BlockSpec((1, 1, gf, bq, 1),
                              lambda bb, hh, ii, jj, off:
-                             (bb, hh // ngf, hh % ngf, ii)),
+                             (bb, hh // ngf, hh % ngf, ii, 0)),
             ],
             out_specs=pl.BlockSpec(
                 (1, 1, gf, bq, hd),
@@ -742,7 +746,7 @@ def _bwd_call(q, k, v, do, lse, delta, offs, plan: AttnPlan, *,
             scratch_shapes=[pltpu.VMEM((gf * bq, hd), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((b, kh, g, sq, hd), q.dtype),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -775,12 +779,12 @@ def _bwd_call(q, k, v, do, lse, delta, offs, plan: AttnPlan, *,
                 pl.BlockSpec((1, 1, gf, dbq, hd_v),
                              lambda bb, hk, jj, gg, ii, off:
                              (bb, hk, gg, ii, 0)),
-                pl.BlockSpec((1, 1, gf, dbq),
+                pl.BlockSpec((1, 1, gf, dbq, 1),
                              lambda bb, hk, jj, gg, ii, off:
-                             (bb, hk, gg, ii)),
-                pl.BlockSpec((1, 1, gf, dbq),
+                             (bb, hk, gg, ii, 0)),
+                pl.BlockSpec((1, 1, gf, dbq, 1),
                              lambda bb, hk, jj, gg, ii, off:
-                             (bb, hk, gg, ii)),
+                             (bb, hk, gg, ii, 0)),
             ],
             out_specs=[
                 pl.BlockSpec((1, 1, dbk, hd),
@@ -799,7 +803,7 @@ def _bwd_call(q, k, v, do, lse, delta, offs, plan: AttnPlan, *,
             jax.ShapeDtypeStruct((b, kh, sk, hd), k.dtype),
             jax.ShapeDtypeStruct((b, kh, sk, hd_v), v.dtype),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary", "arbitrary")),
         interpret=interpret,
@@ -835,7 +839,7 @@ def _flash_bwd_rule(causal, window, plan, kv_len, interpret, res, do):
     # delta_i = rowsum(do · out), elementwise on the unblocked arrays (see
     # models.attention._flash_bwd for why not a blocked dot)
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1)                            # (B, KH, G, S)
+                    axis=-1, keepdims=True)             # (B, KH, G, S, 1)
     dq, dk, dv = _bwd_call(q, k, v, do, lse, delta, offs, plan,
                            causal=causal, window=window, kv_len=kv_len,
                            interpret=interpret)

@@ -23,11 +23,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import autotune
 
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4.x/0.5.x;
-# resolve whichever this jax provides
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
@@ -123,7 +118,7 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
             pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, hd_v), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(cur, q, k_cache, v_cache)

@@ -49,13 +49,23 @@ def flash_attention(q, k, v, q_offset=0.0, *, causal=True, window=0,
 def ssd_scan(x, dt, A, B, C, *, chunk=128, interpret=None):
     """Model layout: x (B,S,H,P), dt (B,S,H), B/C (B,S,N).
 
-    Returns (y (B,S,H,P), state (B,H,P,N)).
+    Returns (y (B,S,H,P), state (B,H,P,N)).  A length that is not a
+    multiple of the chunk is padded with dt=0 steps: no decay and no input
+    contribution, so the state and the real outputs are unaffected.
     """
     interpret = _default_interpret() if interpret is None else interpret
+    s = x.shape[1]
+    chunk = min(chunk, s)
+    pad = (-s) % chunk
+    if pad:
+        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
+        B = jnp.pad(B, ((0, 0), (0, pad), (0, 0)))
+        C = jnp.pad(C, ((0, 0), (0, pad), (0, 0)))
     xt = jnp.transpose(x, (0, 2, 1, 3))
     dtt = jnp.transpose(dt, (0, 2, 1))
     y, st = _ssd.ssd_scan(xt, dtt, A, B, C, chunk=chunk, interpret=interpret)
-    return jnp.transpose(y, (0, 2, 1, 3)), st
+    return jnp.transpose(y, (0, 2, 1, 3))[:, :s], st
 
 
 @functools.partial(jax.jit, static_argnames=("dst_off", "src_off", "size",

@@ -12,10 +12,17 @@ fallback.  Two kernels implement it:
   not be block-aligned; edge tiles are handled by a masked read-modify-write
   so untouched destination rows are preserved bit-exactly.
 
+Mosaic slices and DMAs the (rows, 128) uint8 view only at row offsets that
+are multiples of its sublane tiling (:data:`ALIGN` rows).  A range may start
+at any row, so every block moves an ``ALIGN``-aligned window one tile longer
+than the block: the source window is rotated by the two offsets' residues
+(widened to 32 bits, the only width Mosaic rotates) so its rows line up with
+the destination window, and the valid rows are merged under a mask.
+
 Above :data:`DMA_STAGE_BYTES` of buffer, the batched kernel's
 whole-buffer VMEM residency stops being a plan (a 32 MiB spill buffer
 doesn't fit a 16 MiB VMEM), so ``multi_partition_copy`` re-stages: the
-buffers stay in HBM (``memory_space=pltpu.ANY``) and each grid step
+buffers stay in HBM (``memory_space=pl.ANY``) and each grid step
 moves one autotuner-sized chunk through a double-buffered VMEM stage
 with explicit ``pltpu.make_async_copy`` DMAs — the next chunk's source
 fetch is in flight while the current chunk merges.  Same tables, same
@@ -37,6 +44,9 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import autotune
 
 LANES = 128
+# Row tiling of the (rows, LANES) uint8 view: dynamic row offsets of slices
+# and DMAs must be multiples of it.
+ALIGN = 8
 
 # Buffer size above which multi_partition_copy switches from whole-buffer
 # VMEM residency to the HBM-staged chunked-DMA kernel.
@@ -47,6 +57,27 @@ def dma_staged(dst_bytes: int, src_bytes: int) -> bool:
     """True when a copy over buffers this large takes the DMA-staged
     path (either buffer too big for whole-buffer VMEM residency)."""
     return max(dst_bytes, src_bytes) > DMA_STAGE_BYTES
+
+
+def _aligned(row):
+    """(row rounded down to ALIGN, residue) for a traced row index."""
+    base = pl.multiple_of((row // ALIGN) * ALIGN, ALIGN)
+    return base, row - base
+
+
+def _merge_window(val, cur, shift, lo, n):
+    """Rotate the source window ``val`` down by ``shift`` rows and keep its
+    rows ``[lo, lo + n)`` over the destination window ``cur``."""
+    win = val.shape[0]
+    rolled = pltpu.roll(val.astype(jnp.uint32), shift, 0).astype(val.dtype)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (win, LANES), 0)
+    keep = jnp.logical_and(rows >= lo, rows < lo + n)
+    return jnp.where(keep, rolled, cur)
+
+
+def _window_shift(d_res, s_res, win):
+    """Roll amount that moves source-window row ``s_res`` to ``d_res``."""
+    return (d_res - s_res + win) % win
 
 
 def _copy_kernel(src_ref, dst_in_ref, o_ref):
@@ -143,21 +174,21 @@ def _multi_partition_copy_impl(dst: jax.Array, src: jax.Array,
                                interpret: bool) -> jax.Array:
     total_blocks = int(d_tab.shape[0])
     nd = dst.shape[0]
-    # pad by one block so edge tiles can load/store block_rows full rows;
+    win = block_rows + ALIGN
+    # pad by one window so edge tiles can load/store win full rows;
     # masked RMW keeps the pad rows' (and any untouched rows') contents
-    dst_p = jnp.pad(dst, ((0, block_rows), (0, 0)))
-    src_p = jnp.pad(src, ((0, block_rows), (0, 0)))
+    dst_p = jnp.pad(dst, ((0, win), (0, 0)))
+    src_p = jnp.pad(src, ((0, win), (0, 0)))
 
     def kernel(d_ref, s_ref, n_ref, src_ref, dst_in_ref, o_ref):
         del dst_in_ref  # aliased with o_ref; read through o_ref for RMW
         i = pl.program_id(0)
-        dr = d_ref[i]
-        sr = s_ref[i]
-        nv = n_ref[i]
-        rows = jax.lax.broadcasted_iota(jnp.int32, (block_rows, LANES), 0)
-        val = src_ref[pl.ds(sr, block_rows), :]
-        cur = o_ref[pl.ds(dr, block_rows), :]
-        o_ref[pl.ds(dr, block_rows), :] = jnp.where(rows < nv, val, cur)
+        da, d_res = _aligned(d_ref[i])
+        sa, s_res = _aligned(s_ref[i])
+        val = src_ref[pl.ds(sa, win), :]
+        cur = o_ref[pl.ds(da, win), :]
+        o_ref[pl.ds(da, win), :] = _merge_window(
+            val, cur, _window_shift(d_res, s_res, win), d_res, n_ref[i])
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -166,12 +197,17 @@ def _multi_partition_copy_impl(dst: jax.Array, src: jax.Array,
                   pl.BlockSpec(dst_p.shape, lambda i, *_: (0, 0))],
         out_specs=pl.BlockSpec(dst_p.shape, lambda i, *_: (0, 0)),
     )
+    # whole src + dst blocks, double-buffered by the pipeline, plus the
+    # widened rotate temporaries
+    vmem = 2 * (src_p.size + dst_p.size) + 16 * win * LANES + 2 ** 20
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(dst_p.shape, dst_p.dtype),
         # operand indices include the 3 scalar-prefetch tables: dst_in is 4
         input_output_aliases={4: 0},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=max(vmem, 32 * 2 ** 20)),
         interpret=interpret,
     )(jnp.asarray(d_tab), jnp.asarray(s_tab), jnp.asarray(n_tab),
       src_p, dst_p)
@@ -185,8 +221,9 @@ def _multi_partition_copy_dma(dst: jax.Array, src: jax.Array,
                               interpret: bool) -> jax.Array:
     """HBM-staged variant: buffers never become VMEM-resident blocks.
 
-    src/dst live in ``pltpu.ANY`` (HBM on hardware); each grid step
-    DMAs one ``chunk``-row table entry through a two-slot VMEM stage —
+    src/dst live in ``pl.ANY`` (HBM on hardware); each grid step
+    DMAs one ``chunk``-row table entry (as an ALIGN-aligned window one
+    tile longer) through a two-slot VMEM stage —
     while chunk *i* merges, chunk *i+1*'s source fetch is already in
     flight (started one step ahead on the other slot/semaphore pair).
     The destination chunk is fetched, merged under the valid-row mask
@@ -196,10 +233,11 @@ def _multi_partition_copy_dma(dst: jax.Array, src: jax.Array,
     """
     total_blocks = int(d_tab.shape[0])
     nd = dst.shape[0]
-    # pad by one chunk so edge tiles can move full-chunk DMAs; the
+    win = chunk + ALIGN
+    # pad by one window so edge tiles can move full-window DMAs; the
     # masked merge keeps pad-row (and untouched-row) contents
-    dst_p = jnp.pad(dst, ((0, chunk), (0, 0)))
-    src_p = jnp.pad(src, ((0, chunk), (0, 0)))
+    dst_p = jnp.pad(dst, ((0, win), (0, 0)))
+    src_p = jnp.pad(src, ((0, win), (0, 0)))
 
     def kernel(d_ref, s_ref, n_ref, src_ref, dst_in_ref, o_ref,
                scr, sdst, sem_a, sem_b, sem_d, sem_o):
@@ -208,8 +246,9 @@ def _multi_partition_copy_dma(dst: jax.Array, src: jax.Array,
         n = pl.num_programs(0)
 
         def _src_copy(blk, slot, sem):
+            sa, _ = _aligned(s_ref[blk])
             return pltpu.make_async_copy(
-                src_ref.at[pl.ds(s_ref[blk], chunk)], scr.at[slot], sem)
+                src_ref.at[pl.ds(sa, win)], scr.at[slot], sem)
 
         @pl.when(i == 0)
         def _first():
@@ -225,14 +264,17 @@ def _multi_partition_copy_dma(dst: jax.Array, src: jax.Array,
 
         def _merge(slot, sem):
             _src_copy(i, slot, sem).wait()
+            da, d_res = _aligned(d_ref[i])
+            _, s_res = _aligned(s_ref[i])
             dcp = pltpu.make_async_copy(
-                o_ref.at[pl.ds(d_ref[i], chunk)], sdst, sem_d)
+                o_ref.at[pl.ds(da, win)], sdst, sem_d)
             dcp.start()
             dcp.wait()
-            rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, LANES), 0)
-            scr[slot] = jnp.where(rows < n_ref[i], scr[slot], sdst[...])
+            scr[slot] = _merge_window(scr[slot], sdst[...],
+                                      _window_shift(d_res, s_res, win),
+                                      d_res, n_ref[i])
             ocp = pltpu.make_async_copy(
-                scr.at[slot], o_ref.at[pl.ds(d_ref[i], chunk)], sem_o)
+                scr.at[slot], o_ref.at[pl.ds(da, win)], sem_o)
             ocp.start()
             ocp.wait()
 
@@ -247,12 +289,12 @@ def _multi_partition_copy_dma(dst: jax.Array, src: jax.Array,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(total_blocks,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
-            pltpu.VMEM((2, chunk, LANES), dst.dtype),
-            pltpu.VMEM((chunk, LANES), dst.dtype),
+            pltpu.VMEM((2, win, LANES), dst.dtype),
+            pltpu.VMEM((win, LANES), dst.dtype),
             pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA,
         ],

@@ -75,7 +75,9 @@ def main() -> None:
     else:
         import jax
         import jax.numpy as jnp
+        from repro.launch.compile_cache import enable_compile_cache
         from repro.models.model import LanguageModel
+        enable_compile_cache()
         cfg = get_config(args.arch)
         if args.smoke:
             cfg = cfg.reduced()

@@ -13,6 +13,7 @@ import jax
 
 from repro.configs import get_config
 from repro.data import FileTokens, SyntheticTokens
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.model import LanguageModel
 from repro.optim import OptimizerConfig
@@ -35,6 +36,7 @@ def main() -> None:
     ap.add_argument("--tp", type=int, default=1,
                     help="model-parallel size over local devices")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

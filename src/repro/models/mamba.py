@@ -195,21 +195,13 @@ def _mamba_out(params: Params, y_heads: jax.Array, xh: jax.Array, z: jax.Array,
     return jnp.einsum("bse,ed->bsd", y, params["out_proj"])
 
 
-def mamba_train(params: Params, x: jax.Array, cfg,
-                use_kernel: bool = None) -> jax.Array:
-    """Full-sequence Mamba2 block (training / prefill compute).
-
-    ``use_kernel`` defaults to the backend: Pallas SSD kernel on TPU, the
-    jnp chunked scan elsewhere (REPRO_NO_KERNELS=1 opts out)."""
-    if use_kernel is None:
-        import os
-        use_kernel = (jax.default_backend() == "tpu"
-                      and os.environ.get("REPRO_NO_KERNELS") != "1"
-                      and x.shape[1] % cfg.ssm_chunk == 0)
+def mamba_train(params: Params, x: jax.Array, cfg) -> jax.Array:
+    """Full-sequence Mamba2 block (training / prefill compute): the Pallas
+    SSD kernel on TPU, the jnp chunked scan elsewhere."""
     h, pdim = cfg.ssm_heads, cfg.ssm_head_dim
     z, xs, B, C, dt, A, _ = _mamba_proj(params, x, cfg)
     xh = xs.reshape(*xs.shape[:-1], h, pdim)
-    if use_kernel:
+    if jax.default_backend() == "tpu":
         from repro.kernels import ops as kops
         y, _ = kops.ssd_scan(xh, dt, A, B, C, chunk=cfg.ssm_chunk)
     else:

@@ -373,7 +373,7 @@ def moe_ffn(params: Params, x: jax.Array, cfg
       the O(E)-wasteful baseline, kept for fallback (S not divisible by
       the model axis) and for ``bench_moe``'s comparison.
     """
-    from repro.dist.sharding import current_ctx, moe_bucket_ranges, shard_map
+    from repro.dist.sharding import current_ctx, moe_bucket_ranges
     from jax.sharding import PartitionSpec as P
 
     ctx = current_ctx()
@@ -443,12 +443,12 @@ def moe_ffn(params: Params, x: jax.Array, cfg
             return y.reshape(bl, sl, d), kept.reshape(bl, sl)
 
         xspec = P(dp_b, "model", None)
-        fn = shard_map(
-            inner_a2a, ctx.mesh,
+        fn = jax.shard_map(
+            inner_a2a, mesh=ctx.mesh,
             in_specs=(xspec, xspec, xspec,
                       P("model", fs, None), P("model", fs, None),
                       P("model", None, fs)),
-            out_specs=(xspec, P(dp_b, "model")), check=False)
+            out_specs=(xspec, P(dp_b, "model")), check_vma=False)
         y, kept_b = fn(x, gates_b, idx_b.astype(jnp.int32),
                        params["w_gate"], params["w_up"], params["w_down"])
     else:
@@ -468,12 +468,12 @@ def moe_ffn(params: Params, x: jax.Array, cfg
             return y.reshape(bl, sl, d), kept.reshape(bl, sl)
 
         xspec = P(dp_b, None, None)
-        fn = shard_map(
-            inner, ctx.mesh,
+        fn = jax.shard_map(
+            inner, mesh=ctx.mesh,
             in_specs=(xspec, xspec, xspec,
                       P("model", fs, None), P("model", fs, None),
                       P("model", None, fs)),
-            out_specs=(xspec, P(dp_b, None)), check=False)
+            out_specs=(xspec, P(dp_b, None)), check_vma=False)
         y, kept_b = fn(x, gates_b, idx_b.astype(jnp.int32),
                        params["w_gate"], params["w_up"], params["w_down"])
 

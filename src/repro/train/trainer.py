@@ -87,7 +87,17 @@ class Trainer:
             self.start_step = step
             return state
         self.start_step = 0
-        return init_train_state(self.model, key, self.oc)
+        if self.mesh is None:
+            return init_train_state(self.model, key, self.oc)
+        # initialize straight into the mesh layout: the whole state never
+        # sits on one device (a 3B fp32 state does not fit one chip)
+        from repro.dist.sharding import ShardCtx, param_shardings
+
+        def init(k):
+            return init_train_state(self.model, k, self.oc)
+        shapes = jax.eval_shape(init, key)
+        shardings = param_shardings(shapes, ShardCtx(mesh=self.mesh))
+        return jax.jit(init, out_shardings=shardings)(key)
 
     # ----------------------------------------------------------------- run
 
@@ -112,7 +122,10 @@ class Trainer:
             batch = self.data.get(i)
             batch = {k: jax.numpy.asarray(v) for k, v in batch.items()}
             with use_mesh(self.mesh):
-                holder["state"], metrics = step_fn(holder["state"], batch)
+                out = step_fn(holder["state"], batch)
+            # the clock stops when the step's results exist, not when the
+            # step was enqueued: step_time and the watchdog see the device
+            holder["state"], metrics = jax.block_until_ready(out)
             dt = time.perf_counter() - t0
             durations.append(dt)
             med = float(np.median(durations))

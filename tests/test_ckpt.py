@@ -159,7 +159,7 @@ def test_sharded_save_reshard_on_restore():
     import json, os, tempfile, shutil
     import numpy as np
     import jax
-    from jax.sharding import Mesh
+    from repro.launch.mesh import make_mesh
     from repro import ckpt
     from repro.dist.sharding import ShardCtx, param_shardings
 
@@ -172,7 +172,7 @@ def test_sharded_save_reshard_on_restore():
     shapes = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
 
-    mesh8 = Mesh(np.array(jax.devices()).reshape(2, 4), ("data", "model"))
+    mesh8 = make_mesh((2, 4), ("data", "model"))
     sh8 = param_shardings(shapes, ShardCtx(mesh=mesh8))
     dev = jax.tree_util.tree_map(jax.device_put, tree, sh8)
     tmp = tempfile.mkdtemp()
@@ -201,11 +201,9 @@ def test_sharded_save_reshard_on_restore():
             tree["opt"]["step"], np.asarray(got["opt"]["step"]))
 
     check(None)                                        # plain host restore
-    mesh2 = Mesh(np.array(jax.devices()[:2]).reshape(1, 2),
-                 ("data", "model"))
+    mesh2 = make_mesh((1, 2), ("data", "model"), devices=jax.devices()[:2])
     check(param_shardings(shapes, ShardCtx(mesh=mesh2)))
-    mesh1 = Mesh(np.array(jax.devices()[:1]).reshape(1, 1),
-                 ("data", "model"))
+    mesh1 = make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
     check(param_shardings(shapes, ShardCtx(mesh=mesh1)))
     check(param_shardings(shapes, ShardCtx(mesh=mesh2, pure_dp=True)))
 
@@ -223,7 +221,7 @@ def test_sharded_save_restores_on_other_writer_count():
     import tempfile, shutil
     import numpy as np
     import jax
-    from jax.sharding import Mesh
+    from repro.launch.mesh import make_mesh
     from repro import ckpt
     from repro.dist.sharding import ShardCtx, param_shardings
 
@@ -231,7 +229,7 @@ def test_sharded_save_restores_on_other_writer_count():
     tree = {"w_up": rng.normal(size=(16, 64)).astype(np.float32)}
     shapes = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
-    mesh = Mesh(np.array(jax.devices()).reshape(2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     dev = jax.tree_util.tree_map(
         jax.device_put, tree, param_shardings(shapes, ShardCtx(mesh=mesh)))
     tmp = tempfile.mkdtemp()

@@ -39,7 +39,8 @@ def test_moe_shardmap_parity():
 
     y_ref, aux_ref = M.moe_ffn(params, x, cfg)          # no mesh
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     with use_mesh(mesh):
         y_sh, aux_sh = jax.jit(lambda p, xx: M.moe_ffn(p, xx, cfg))(params, x)
 
@@ -81,7 +82,8 @@ def test_seq_parallel_attention_parity():
 
     ref = causal_attention(q, k, v, cfg=cfg)            # no mesh
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))     # 6 % 4 != 0 → seq
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))     # 6 % 4 != 0 → seq
     with use_mesh(mesh):
         got = jax.jit(lambda a, b_, c: causal_attention(a, b_, c, cfg=cfg))(
             q, k, v)
@@ -121,7 +123,8 @@ def test_flash_decode_lse_combine_parity():
     o_ref, kc_ref, vc_ref = decode_update_and_attend(
         q, kn, vn, kc, vc, cur, cfg=cfg)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     with use_mesh(mesh):
         o, kc2, vc2 = jax.jit(lambda *a: decode_update_and_attend(
             *a, cfg=cfg))(q, kn, vn, kc, vc, cur)
@@ -140,7 +143,8 @@ def test_param_shardings_cover_all_archs():
     from repro.dist.sharding import ShardCtx, param_shardings, use_mesh
     from repro.launch.specs import params_only_specs
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     ctx = ShardCtx(mesh)
     for arch in all_arch_names():
         cfg = get_config(arch)
@@ -182,7 +186,8 @@ def test_train_step_sharded_matches_single_device():
     b = {k: jnp.asarray(v) for k, v in data.get(0).items()}
     s1b, m1 = jax.jit(step)(s1, b)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     s2 = init_train_state(model, jax.random.PRNGKey(0), oc)
     with use_mesh(mesh):
         s2b, m2 = jax.jit(step)(s2, b)

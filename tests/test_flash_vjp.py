@@ -208,7 +208,8 @@ def test_context_parallel_stripes_run_pallas_vjp():
 
     ref = causal_attention(q, k, v, cfg=cfg, window=cfg.sliding_window)
     g_ref = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     with use_mesh(mesh):
         got = jax.jit(lambda a, b_, c: causal_attention(
             a, b_, c, cfg=cfg, window=cfg.sliding_window))(q, k, v)
@@ -253,7 +254,8 @@ def test_use_mesh_train_step_runs_pallas_vjp():
     b = {k: jnp.asarray(v) for k, v in data.get(0).items()}
     s1b, m1 = jax.jit(step)(s1, b)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     s2 = init_train_state(model, jax.random.PRNGKey(0), oc)
     with use_mesh(mesh):
         s2b, m2 = jax.jit(step)(s2, b)

@@ -85,7 +85,8 @@ def test_collective_parsing_shapes():
         import sys
         sys.path.insert(0, "src")
         from repro.launch import hlo_cost
-        mesh = jax.make_mesh((8,), ("model",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("model",))
         def f(x):
             return jnp.sum(x)
         fn = jax.jit(f, in_shardings=NamedSharding(mesh, P("model")),

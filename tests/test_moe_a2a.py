@@ -56,7 +56,8 @@ def test_a2a_psum_oracle_parity():
     (l_ref, (y_ref, a_ref)), g_ref = jax.value_and_grad(
         loss(cfg), has_aux=True)(params, x)          # no mesh: oracle
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     outs = {}
     for dispatch in ("a2a", "psum"):
         c = dataclasses.replace(cfg, moe_dispatch=dispatch)
@@ -205,7 +206,8 @@ def test_bucket_ranges_are_section6_partitions():
     from repro.core import NULL_GUID, Runtime, spawn_main
     from repro.dist.sharding import ShardCtx, moe_bucket_ranges
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     ctx = ShardCtx(mesh)
     checked = 0
     for e, cap, d, item in ((8, 3, 16, 4), (64, 5, 128, 4),
@@ -288,7 +290,8 @@ def test_a2a_sharded_drop_determinism():
     params = M.moe_init(jax.random.PRNGKey(0), cfg)
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model))
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     with use_mesh(mesh):
         fn = jax.jit(lambda p, xx: M.moe_ffn(p, xx, cfg))
         y1, a1 = fn(params, x)

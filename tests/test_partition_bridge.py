@@ -30,7 +30,8 @@ def test_param_shardings_are_valid_section6_partitions():
     from repro.launch.specs import params_only_specs
     from repro.core import NULL_GUID, Runtime, spawn_main
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     ctx = ShardCtx(mesh)
 
     checked = [0]
@@ -113,7 +114,8 @@ def test_partition_tree_of_properties_hypothesis():
     def prop(case):
         mi, dims, spec, itemsize = case
         mesh_shape, axes = MESHES[mi]
-        mesh = jax.make_mesh(mesh_shape, axes)
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh(mesh_shape, axes)
         sizes = dict(zip(axes, mesh_shape))
         sh = NamedSharding(mesh, P(*spec))
         parts = partition_tree_of(dims, itemsize, sh)
@@ -178,7 +180,8 @@ def test_pure_dp_train_parity():
     s1 = init_train_state(model, jax.random.PRNGKey(0), oc)
     s1b, m1 = jax.jit(step)(s1, b)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     s2 = init_train_state(model, jax.random.PRNGKey(0), oc)
     with use_mesh(mesh, pure_dp=True):
         s2b, m2 = jax.jit(step)(s2, b)

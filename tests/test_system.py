@@ -59,3 +59,22 @@ def test_train_checkpoint_serve_roundtrip(tmp_path):
             hits += 1
         tok = jnp.asarray([[want_i]], jnp.int32)
     assert hits >= 1
+
+
+def test_compile_cache_dir(monkeypatch):
+    """The persistent compile cache sits at JAX_COMPILATION_CACHE_DIR when
+    that is set, else at a fixed path inside the checkout."""
+    import os
+    from repro.launch import compile_cache
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert compile_cache.enable_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == was
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        got = compile_cache.enable_compile_cache()
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert got == os.path.join(root, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

@@ -98,3 +98,23 @@ def test_trainer_with_file_tokens(setup, tmp_path):
     tr.run(state, 5)
     assert len(tr.history) == 5
     assert all(np.isfinite(h["ce_loss"]) for h in tr.history)
+
+
+def test_init_under_mesh_places_state_on_the_mesh(setup):
+    """With a mesh, the initial state is built in the mesh layout (it never
+    has to fit one device) and matches the unsharded initialization: the
+    same random draws, up to the rounding of jit's fused init scaling."""
+    from jax.sharding import NamedSharding
+    from repro.launch.mesh import make_host_mesh
+    cfg, model, oc, data = setup
+    mesh = make_host_mesh()
+    sharded = Trainer(model, oc, data, TrainerConfig(),
+                      mesh=mesh).init_or_restore(jax.random.PRNGKey(0))
+    plain = Trainer(model, oc, data,
+                    TrainerConfig()).init_or_restore(jax.random.PRNGKey(0))
+    leaves = jax.tree_util.tree_leaves(sharded)
+    assert all(isinstance(a.sharding, NamedSharding)
+               and a.sharding.mesh == mesh for a in leaves)
+    for a, b in zip(leaves, jax.tree_util.tree_leaves(plain)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-6, atol=1e-7)
