@@ -1,0 +1,3 @@
+"""``launch_ms`` in the cells whose end-to-end rate is ``serve_tok_s``:
+the same reading, moving another metric."""
+from chipbench.metrics.launch_ms import read  # noqa: F401

@@ -65,7 +65,7 @@ from .guid import (
     is_null,
 )
 from .io_queue import IoQueue
-from ..monitoring import Monitor, Registry
+from ..monitoring import Monitor, Registry, spans
 from .messages import (
     MCreate,
     MDbCopy,
@@ -472,7 +472,21 @@ class Runtime:
     # --------------------------------------------------------------- run loop
 
     def run(self, until: Optional[float] = None) -> Stats:
-        """Process events until quiescent, shutdown, or ``until``."""
+        """Process events until quiescent, shutdown, or ``until``.
+
+        While a profiler trace runs, the call is an ``ocr.run`` span
+        counting the ``tasks`` it executed; its self time (the span less
+        the spans of the task bodies inside it) is the runtime's own
+        wall-clock cost."""
+        if not spans.enabled():
+            return self._run(until)
+        n0 = self.stats.tasks_executed
+        with spans.span("ocr.run") as sp:
+            out = self._run(until)
+            sp.set(tasks=self.stats.tasks_executed - n0)
+        return out
+
+    def _run(self, until: Optional[float]) -> Stats:
         while self._heap and not self.shutdown_requested:
             t, tick, kind, payload = heapq.heappop(self._heap)
             if until is not None and t > until:

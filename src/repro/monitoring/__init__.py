@@ -12,7 +12,14 @@ Enable per-runtime with ``Runtime(monitor=True)`` (or the
 ``REPRO_MONITOR`` environment variable); off by default — hook sites
 follow the sanitizer's one-``is None``-check pattern so virtual
 metrics stay bit-identical either way.
+
+Its wall-clock side is ``repro.monitoring.spans``: spans and counters at
+the layer boundaries of the runtime (``ocr.run``), the serve engine
+(``serve.*``) and the trainer (``train.*``), recorded only while a JAX
+profiler trace runs, on the same clock as the device's operations.
 """
+from . import spans
 from .registry import DEFAULT_LATENCY_EDGES, Histogram, Monitor, Registry
 
-__all__ = ["DEFAULT_LATENCY_EDGES", "Histogram", "Monitor", "Registry"]
+__all__ = ["DEFAULT_LATENCY_EDGES", "Histogram", "Monitor", "Registry",
+           "spans"]

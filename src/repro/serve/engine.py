@@ -31,6 +31,7 @@ import numpy as np
 
 from repro.core import (DbMode, EDT_PROP_MAPPED, NULL_GUID, Runtime, TaskCtx,
                         spawn_main)
+from repro.monitoring import spans
 
 
 # ------------------------------------------------------------------ workload
@@ -124,7 +125,14 @@ class SyntheticBackend:
 
 class ModelBackend:
     """Real paged jax serving: per-layer page pools plus the jitted
-    prefill-into-pages / paged-decode steps from ``repro.serve.steps``."""
+    prefill-into-pages / paged-decode steps from ``repro.serve.steps``.
+
+    Each call is two spans while the profiler traces: ``serve.dispatch``
+    from entry to the jitted call's return (host arrays, argument
+    transfers, enqueue), carrying the device allocator's
+    ``bytes_in_use`` and ``largest_free_block_bytes`` at entry, and
+    ``serve.readback``, the host read of the tokens that waits for the
+    device."""
 
     def __init__(self, model, params, *, pool_pages: int, page_size: int,
                  prompt_pad: int):
@@ -150,27 +158,42 @@ class ModelBackend:
         self._prefill = make_paged_prefill_step(model, page_size)
         self._decode = make_paged_decode_step(model)
 
+    def _dispatch(self):
+        if not spans.enabled():
+            return spans.OFF
+        dev = min(self.k_pools.devices(), key=lambda d: d.id)
+        mem = dev.memory_stats() or {}
+        return spans.span("serve.dispatch", **{
+            k: int(mem[k]) for k in ("bytes_in_use",
+                                     "largest_free_block_bytes") if k in mem})
+
     def prefill(self, row: int, req: Request, pages: List[int]) -> int:
         import jax.numpy as jnp
-        plen = len(req.prompt)
-        if plen > self.prompt_pad:
-            raise ValueError(f"prompt {plen} > prompt_pad {self.prompt_pad}")
-        tk = np.zeros((1, self.prompt_pad), np.int32)
-        tk[0, :plen] = req.prompt
-        pg = np.full(self.prompt_pad // self.page, self.pool_pages, np.int32)
-        pg[: len(pages)] = pages
-        nt, _, self.k_pools, self.v_pools = self._prefill(
-            self.params, self.k_pools, self.v_pools, jnp.asarray(tk),
-            jnp.int32(plen), jnp.asarray(pg))
-        return int(nt)
+        with self._dispatch():
+            plen = len(req.prompt)
+            if plen > self.prompt_pad:
+                raise ValueError(
+                    f"prompt {plen} > prompt_pad {self.prompt_pad}")
+            tk = np.zeros((1, self.prompt_pad), np.int32)
+            tk[0, :plen] = req.prompt
+            pg = np.full(self.prompt_pad // self.page, self.pool_pages,
+                         np.int32)
+            pg[: len(pages)] = pages
+            nt, _, self.k_pools, self.v_pools = self._prefill(
+                self.params, self.k_pools, self.v_pools, jnp.asarray(tk),
+                jnp.int32(plen), jnp.asarray(pg))
+        with spans.span("serve.readback"):
+            return int(nt)
 
     def decode_step(self, page_table, cur_lens, active, tokens, rids):
         import jax.numpy as jnp
-        nt, _, self.k_pools, self.v_pools, _ = self._decode(
-            self.params, self.k_pools, self.v_pools,
-            jnp.asarray(page_table), jnp.asarray(cur_lens),
-            jnp.asarray(active), jnp.asarray(tokens))
-        return np.asarray(nt)
+        with self._dispatch():
+            nt, _, self.k_pools, self.v_pools, _ = self._decode(
+                self.params, self.k_pools, self.v_pools,
+                jnp.asarray(page_table), jnp.asarray(cur_lens),
+                jnp.asarray(active), jnp.asarray(tokens))
+        with spans.span("serve.readback"):
+            return np.asarray(nt)
 
     def evict_row(self, row: int, pages: List[int]) -> bytes:
         import jax.numpy as jnp
@@ -225,6 +248,13 @@ class ServeEngine:
     ``max_pages`` page-table width.  ``resident_budget`` (data blocks per
     node) arms the runtime's spill threshold: session archives past it
     write back to disk through the IO queue and resume via grant deferral.
+
+    While a profiler trace runs, every pass of ``run``'s loop is a
+    ``serve.iteration`` span (counters ``queued``, ``active`` rows,
+    ``free_pages``) holding ``serve.admit`` (``rid``), ``serve.pages``
+    (``pages``: carved, or handed back when negative), ``serve.prefill``,
+    ``serve.decode`` (``rows``), ``serve.evict`` / ``serve.resume``
+    (``rid``) and the ``ocr.run`` spans of the runtime flushes.
     """
 
     def __init__(self, backend, *, b_cap: int, pool_pages: int,
@@ -352,29 +382,31 @@ class ServeEngine:
         """Carve ``n`` fresh pages for ``sess`` out of the shared cache
         block — one ``db_partition`` call, so overlap with any live page
         is a hard runtime error, not a silent corruption."""
-        while len(self.free_pages) < n:
-            if not self._evict_one(protect=sess):
-                raise RuntimeError(
-                    f"page pool exhausted: {n} pages needed, "
-                    f"{len(self.free_pages)} free, nothing evictable")
-        phys = [self.free_pages.pop(0) for _ in range(n)]
-        pb = self.backend.page_bytes
-        guids = self.ctx.db_partition(
-            self.cache_db, [(p * pb, pb) for p in phys])
-        row = sess.slot
-        for p in phys:
-            self.page_table[row, len(sess.pages)] = p
-            sess.pages.append(p)
-        sess.page_guids.extend(guids)
+        with spans.span("serve.pages", pages=n):
+            while len(self.free_pages) < n:
+                if not self._evict_one(protect=sess):
+                    raise RuntimeError(
+                        f"page pool exhausted: {n} pages needed, "
+                        f"{len(self.free_pages)} free, nothing evictable")
+            phys = [self.free_pages.pop(0) for _ in range(n)]
+            pb = self.backend.page_bytes
+            guids = self.ctx.db_partition(
+                self.cache_db, [(p * pb, pb) for p in phys])
+            row = sess.slot
+            for p in phys:
+                self.page_table[row, len(sess.pages)] = p
+                sess.pages.append(p)
+            sess.page_guids.extend(guids)
 
     def _release_pages(self, sess: _Session) -> None:
-        for g in sess.page_guids:
-            self.ctx.db_destroy(g)
-        self._flush()                     # land the destroys before reuse
-        self.free_pages.extend(sess.pages)
-        row = sess.slot
-        self.page_table[row, :] = self.pool_pages
-        sess.pages, sess.page_guids = [], []
+        with spans.span("serve.pages", pages=-len(sess.pages)):
+            for g in sess.page_guids:
+                self.ctx.db_destroy(g)
+            self._flush()                 # land the destroys before reuse
+            self.free_pages.extend(sess.pages)
+            row = sess.slot
+            self.page_table[row, :] = self.pool_pages
+            sess.pages, sess.page_guids = [], []
 
     # -- admission ----------------------------------------------------------
 
@@ -407,7 +439,8 @@ class ServeEngine:
 
         plen = len(req.prompt)
         self._alloc_pages(sess, (plen + self.page - 1) // self.page)
-        first = self.backend.prefill(slot, req, sess.pages)
+        with spans.span("serve.prefill"):
+            first = self.backend.prefill(slot, req, sess.pages)
         self.t += (self.cost.prefill_base
                    + self.cost.prefill_per_tok * plen)
         self._flush()
@@ -461,53 +494,56 @@ class ServeEngine:
         """Demote a session: serialize its pages into an archive block,
         destroy the page partitions, and let the spill policy write the
         cold archive back to disk."""
-        raw = self.backend.evict_row(sess.slot, sess.pages)
-        g, buf = self.ctx.db_create(max(len(raw), 1))
-        if raw:
-            buf[: len(raw)] = np.frombuffer(raw, np.uint8)
-        sess.archive = g
-        sess.n_pages_archived = len(sess.pages)
-        self._release_pages(sess)
-        self.active[sess.slot] = False
-        sess.state = "evicted"
-        self.evictions += 1
-        self.rt.spill_check(0)           # the archive is new cold memory
-        self._flush()
+        with spans.span("serve.evict", rid=sess.req.rid):
+            raw = self.backend.evict_row(sess.slot, sess.pages)
+            g, buf = self.ctx.db_create(max(len(raw), 1))
+            if raw:
+                buf[: len(raw)] = np.frombuffer(raw, np.uint8)
+            sess.archive = g
+            sess.n_pages_archived = len(sess.pages)
+            self._release_pages(sess)
+            self.active[sess.slot] = False
+            sess.state = "evicted"
+            self.evictions += 1
+            self.rt.spill_check(0)           # the archive is new cold memory
+            self._flush()
 
     def _start_resume(self, sess: _Session) -> None:
         """Acquire the (possibly spilled) archive RO from a task: a
         spilled archive defers the grant until the IO-queue read lands —
         the same path §5 unread file chunks take."""
-        sess.state = "resuming"
-        eng = self
+        with spans.span("serve.resume", rid=sess.req.rid):
+            sess.state = "resuming"
+            eng = self
 
-        def _body(paramv, depv, api):
-            eng._resume_ready[sess.req.rid] = bytes(depv[0].ptr)
-            return NULL_GUID
+            def _body(paramv, depv, api):
+                eng._resume_ready[sess.req.rid] = bytes(depv[0].ptr)
+                return NULL_GUID
 
-        def _main(paramv, depv, api):
-            tmpl = api.edt_template_create(_body, 0, 1)
-            api.edt_create(tmpl, depv=[sess.archive],
-                           dep_modes=[DbMode.RO], duration=eng._eps)
-            return NULL_GUID
+            def _main(paramv, depv, api):
+                tmpl = api.edt_template_create(_body, 0, 1)
+                api.edt_create(tmpl, depv=[sess.archive],
+                               dep_modes=[DbMode.RO], duration=eng._eps)
+                return NULL_GUID
 
-        spawn_main(self.rt, _main, duration=self._eps)
-        self._flush()
+            spawn_main(self.rt, _main, duration=self._eps)
+            self._flush()
 
     def _finish_resume(self, sess: _Session) -> None:
-        raw = self._resume_ready.pop(sess.req.rid)
-        n = sess.n_pages_archived
-        self._alloc_pages(sess, n)
-        self.backend.restore_row(sess.slot, sess.pages, raw, sess.cur)
-        self.ctx.db_destroy(sess.archive)
-        sess.archive = None
-        sess.state = "running"
-        sess.just_resumed = True
-        self.cur_lens[sess.slot] = sess.cur
-        self.tokens[sess.slot] = sess.last_tok
-        self.active[sess.slot] = True
-        self.resumes += 1
-        self._flush()
+        with spans.span("serve.resume", rid=sess.req.rid):
+            raw = self._resume_ready.pop(sess.req.rid)
+            n = sess.n_pages_archived
+            self._alloc_pages(sess, n)
+            self.backend.restore_row(sess.slot, sess.pages, raw, sess.cur)
+            self.ctx.db_destroy(sess.archive)
+            sess.archive = None
+            sess.state = "running"
+            sess.just_resumed = True
+            self.cur_lens[sess.slot] = sess.cur
+            self.tokens[sess.slot] = sess.last_tok
+            self.active[sess.slot] = True
+            self.resumes += 1
+            self._flush()
 
     # -- main loop ----------------------------------------------------------
 
@@ -520,92 +556,101 @@ class ServeEngine:
         total = len(requests)
 
         while n_done < total:
-            self._flush()
-            if self.monitor_interval > 0.0 and self.t >= next_snap:
-                snap = self.monitor()
-                self.monitor_snapshots.append(snap)
-                if self.on_monitor is not None:
-                    self.on_monitor(self.t, snap)
-                next_snap = self.t + self.monitor_interval
-            while pending and pending[0].arrival <= self.t:
-                queued.append(pending.popleft())
+            with spans.span("serve.iteration") as it:
+                self._flush()
+                if self.monitor_interval > 0.0 and self.t >= next_snap:
+                    snap = self.monitor()
+                    self.monitor_snapshots.append(snap)
+                    if self.on_monitor is not None:
+                        self.on_monitor(self.t, snap)
+                    next_snap = self.t + self.monitor_interval
+                while pending and pending[0].arrival <= self.t:
+                    queued.append(pending.popleft())
 
-            # resumed sessions rejoin before new admissions (they arrived
-            # first); only land ones whose archive bytes are back
-            for sess in list(self.sessions.values()):
-                if (sess.state == "resuming"
-                        and sess.req.rid in self._resume_ready
-                        and len(self.free_pages) > sess.n_pages_archived):
-                    self._finish_resume(sess)
+                # resumed sessions rejoin before new admissions (they
+                # arrived first); only land ones whose archive bytes are back
+                for sess in list(self.sessions.values()):
+                    if (sess.state == "resuming"
+                            and sess.req.rid in self._resume_ready
+                            and len(self.free_pages)
+                            > sess.n_pages_archived):
+                        self._finish_resume(sess)
 
-            # admissions: prefill interleaves with the running batch
-            while queued and self.free_slots:
-                if self._io_backpressured():
-                    # pages and a slot may be free — the page/slot-only
-                    # gate would admit — but the IO plane is saturated:
-                    # defer until the backlog drains (its MIoDone events
-                    # guarantee forward progress below)
-                    self.deferred_admissions += 1
-                    break
-                req = queued.popleft()
-                need = (len(req.prompt) + self.page - 1) // self.page
-                if (len(self.free_pages) < need + 1
-                        and not any(s.state == "running"
-                                    for s in self.sessions.values())):
-                    queued.appendleft(req)   # wait for pages, not deadlock
-                    break
-                before = self._done_count(requests)
-                self._admit(req)
-                n_done += self._done_count(requests) - before
+                # admissions: prefill interleaves with the running batch
+                while queued and self.free_slots:
+                    if self._io_backpressured():
+                        # pages and a slot may be free — the page/slot-only
+                        # gate would admit — but the IO plane is saturated:
+                        # defer until the backlog drains (its MIoDone events
+                        # guarantee forward progress below)
+                        self.deferred_admissions += 1
+                        break
+                    req = queued.popleft()
+                    need = (len(req.prompt) + self.page - 1) // self.page
+                    if (len(self.free_pages) < need + 1
+                            and not any(s.state == "running"
+                                        for s in self.sessions.values())):
+                        # wait for pages, not deadlock
+                        queued.appendleft(req)
+                        break
+                    before = self._done_count(requests)
+                    with spans.span("serve.admit", rid=req.rid):
+                        self._admit(req)
+                    n_done += self._done_count(requests) - before
 
-            # kick resume reads for evicted sessions
-            for sess in self.sessions.values():
-                if sess.state == "evicted":
-                    self._start_resume(sess)
+                # kick resume reads for evicted sessions
+                for sess in self.sessions.values():
+                    if sess.state == "evicted":
+                        self._start_resume(sess)
 
-            rows = [s for s in self.sessions.values() if s.state == "running"]
-            if not rows:
-                nxt = []
-                if pending:
-                    nxt.append(pending[0].arrival)
-                if self.rt._heap:
-                    nxt.append(self.rt._heap[0][0])
-                if not nxt:
-                    if queued:
-                        raise RuntimeError("serve engine stalled with "
-                                           f"{len(queued)} queued requests")
-                    break
-                self.t = max(self.t, min(nxt))
-                continue
+                rows = [s for s in self.sessions.values()
+                        if s.state == "running"]
+                it.set(queued=len(queued), active=len(rows),
+                       free_pages=len(self.free_pages))
+                if not rows:
+                    nxt = []
+                    if pending:
+                        nxt.append(pending[0].arrival)
+                    if self.rt._heap:
+                        nxt.append(self.rt._heap[0][0])
+                    if not nxt:
+                        if queued:
+                            raise RuntimeError(
+                                "serve engine stalled with "
+                                f"{len(queued)} queued requests")
+                        break
+                    self.t = max(self.t, min(nxt))
+                    continue
 
-            # grow pages for rows whose next token crosses a boundary;
-            # _alloc_pages may evict a session that is still in this
-            # snapshot, so re-check state as we go
-            for sess in rows:
-                if (sess.state == "running"
-                        and sess.cur // self.page >= len(sess.pages)):
-                    self._alloc_pages(sess, 1)
-            rows = [s for s in rows if s.state == "running"]
-            if not rows:
-                continue
+                # grow pages for rows whose next token crosses a boundary;
+                # _alloc_pages may evict a session that is still in this
+                # snapshot, so re-check state as we go
+                for sess in rows:
+                    if (sess.state == "running"
+                            and sess.cur // self.page >= len(sess.pages)):
+                        self._alloc_pages(sess, 1)
+                rows = [s for s in rows if s.state == "running"]
+                if not rows:
+                    continue
 
-            nt = self.backend.decode_step(self.page_table, self.cur_lens,
-                                          self.active, self.tokens,
-                                          self.rids)
-            self.t += (self.cost.decode_base
-                       + self.cost.decode_per_row * self.b_cap)
-            for sess in rows:
-                row = sess.slot
-                sess.just_resumed = False
-                sess.cur += 1
-                self.cur_lens[row] = sess.cur
-                sess.produced += 1
-                sess.last_tok = int(nt[row])
-                self.tokens[row] = sess.last_tok
-                sess.req.out.append(sess.last_tok)
-                if sess.produced >= sess.req.gen:
-                    self._retire(sess)
-                    n_done += 1
+                with spans.span("serve.decode", rows=len(rows)):
+                    nt = self.backend.decode_step(
+                        self.page_table, self.cur_lens, self.active,
+                        self.tokens, self.rids)
+                self.t += (self.cost.decode_base
+                           + self.cost.decode_per_row * self.b_cap)
+                for sess in rows:
+                    row = sess.slot
+                    sess.just_resumed = False
+                    sess.cur += 1
+                    self.cur_lens[row] = sess.cur
+                    sess.produced += 1
+                    sess.last_tok = int(nt[row])
+                    self.tokens[row] = sess.last_tok
+                    sess.req.out.append(sess.last_tok)
+                    if sess.produced >= sess.req.gen:
+                        self._retire(sess)
+                        n_done += 1
 
         self._flush()
         return self._metrics(requests)

@@ -39,7 +39,7 @@ def make_paged_prefill_step(model: LanguageModel, page_size: int):
     """Prefill one request straight into its pages.
 
     Returned step signature:
-      step(params, k_pools, v_pools, tokens, plen, pages)
+      serve_prefill(params, k_pools, v_pools, tokens, plen, pages)
         tokens: (1, Spad) int32, right-padded — Spad must be a multiple of
           ``page_size`` and is a static bucket (one trace per bucket);
         plen: () int32 true prompt length (logits read position plen-1;
@@ -55,7 +55,8 @@ def make_paged_prefill_step(model: LanguageModel, page_size: int):
     if cfg.family not in ("dense", "vlm") or getattr(cfg, "use_mla", False):
         raise ValueError(f"paged serving supports dense GQA, not {cfg.family}")
 
-    def step(params, k_pools, v_pools, tokens, plen, pages):
+    @jax.named_scope("serve_prefill")
+    def serve_prefill(params, k_pools, v_pools, tokens, plen, pages):
         x = jnp.take(params["embedding"], tokens, axis=0).astype(_dtype(cfg.dtype))
         positions = jnp.arange(tokens.shape[1])[None, :]
 
@@ -83,14 +84,15 @@ def make_paged_prefill_step(model: LanguageModel, page_size: int):
         next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return next_tok, logits.astype(jnp.float32), k_pools, v_pools
 
-    return jax.jit(step)
+    return jax.jit(serve_prefill)
 
 
 def make_paged_decode_step(model: LanguageModel):
     """One continuous-batching decode step over the paged pools.
 
     Returned step signature:
-      step(params, k_pools, v_pools, page_table, cur_lens, active, tokens)
+      serve_decode(params, k_pools, v_pools, page_table, cur_lens, active,
+                   tokens)
         page_table: (B, max_pages) int32; cur_lens: (B,) int32 tokens
         already cached per row; active: (B,) bool; tokens: (B,) int32 last
         sampled token per row.
@@ -104,7 +106,9 @@ def make_paged_decode_step(model: LanguageModel):
     if cfg.family not in ("dense", "vlm") or getattr(cfg, "use_mla", False):
         raise ValueError(f"paged serving supports dense GQA, not {cfg.family}")
 
-    def step(params, k_pools, v_pools, page_table, cur_lens, active, tokens):
+    @jax.named_scope("serve_decode")
+    def serve_decode(params, k_pools, v_pools, page_table, cur_lens, active,
+                     tokens):
         x = jnp.take(params["embedding"], tokens[:, None],
                      axis=0).astype(_dtype(cfg.dtype))      # (B, 1, D)
         pos = cur_lens[:, None]                             # (B, 1) per row
@@ -134,4 +138,4 @@ def make_paged_decode_step(model: LanguageModel):
         return (next_tok, logits.astype(jnp.float32), k_pools, v_pools,
                 cur_new)
 
-    return jax.jit(step)
+    return jax.jit(serve_decode)

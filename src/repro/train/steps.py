@@ -64,6 +64,8 @@ def make_train_step(model: LanguageModel, oc: OptimizerConfig):
         m = {k: v / a if k not in summed else v for k, v in m.items()}
         return g, m
 
+    # named in the device trace, beside the jitted program's own name
+    @jax.named_scope("train_step")
     def train_step(state: TrainState, batch: Dict[str, jax.Array]
                    ) -> Tuple[TrainState, Dict[str, jax.Array]]:
         grads, metrics = grads_of(state["params"], batch)

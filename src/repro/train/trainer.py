@@ -33,6 +33,7 @@ from repro.core import (DbMode, EDT_PROP_MAPPED, NULL_GUID,
                         Runtime, UNINITIALIZED_GUID, spawn_main)
 from repro.dist.sharding import use_mesh
 from repro.models.model import LanguageModel
+from repro.monitoring import spans
 from repro.optim import OptimizerConfig
 from .steps import init_train_state, make_train_step
 
@@ -118,56 +119,9 @@ class Trainer:
             if tc.fail_at_step >= 0 and i == tc.fail_at_step:
                 api.rt.kill_node(0)      # fail-stop: nothing after this runs
                 return NULL_GUID
-            t0 = time.perf_counter()
-            batch = self.data.get(i)
-            batch = {k: jax.numpy.asarray(v) for k, v in batch.items()}
-            with use_mesh(self.mesh):
-                out = step_fn(holder["state"], batch)
-            # the clock stops when the step's results exist, not when the
-            # step was enqueued: step_time and the watchdog see the device
-            holder["state"], metrics = jax.block_until_ready(out)
-            dt = time.perf_counter() - t0
-            durations.append(dt)
-            med = float(np.median(durations))
-            if len(durations) > 5 and dt > tc.straggler_factor * med:
-                self.straggler_steps.append(i)
-            m = {k: float(v) for k, v in metrics.items()}
-            m["step"] = i
-            m["step_time"] = dt
-            self.history.append(m)
-            # stamp step metrics into the monitoring registry: the train.*
-            # namespace is live alongside the runtime's io.*/spill.* gauges,
-            # which is what an elastic supervisor would watch mid-run
-            reg = rt.registry
-            reg.set("train.step", float(i))
-            reg.set("train.loss", m.get("loss", 0.0))
-            reg.set("train.step_time_s", dt)
-            reg.inc("train.steps")
-            if rt._mon is not None:
-                reg.histogram("train.step_wall_s").observe(dt)
-            if tc.ckpt_every and tc.ckpt_dir and (i + 1) % tc.ckpt_every == 0:
-                # checkpoint hangs off this step's event; §5 chunked write,
-                # §3 issue-now/resolve-later.  async_ckpt snapshots at
-                # issue time and overlaps inside the runtime's IO queue
-                # (virtual time); the call itself completes before the
-                # next step runs.  Under a mesh the NamedShardings ride
-                # along and
-                # ckpt.save takes the §6 sharded path: each node writes
-                # exactly its own byte ranges, no host-side gather.
-                if self.mesh is not None:
-                    with use_mesh(self.mesh):
-                        if tc.async_ckpt:
-                            self._ckpt_threads.append(ckpt.async_save(
-                                tc.ckpt_dir, holder["state"], i + 1))
-                        else:
-                            ckpt.save(tc.ckpt_dir, holder["state"], i + 1)
-                else:
-                    host = jax.tree_util.tree_map(np.asarray, holder["state"])
-                    if tc.async_ckpt:
-                        self._ckpt_threads.append(
-                            ckpt.async_save(tc.ckpt_dir, host, i + 1))
-                    else:
-                        ckpt.save(tc.ckpt_dir, host, i + 1)
+            with spans.span("train.step", step=i):
+                self._step(i, step_fn, holder, durations, rt.registry,
+                           rt._mon is not None)
             # the paper's wavefront pattern: this task satisfies the next
             # step task's pre-slot via the §4 labeled map
             if idx + 1 < num_steps:
@@ -201,3 +155,67 @@ class Trainer:
         self.last_runtime_stats = rt.stats
         self.registry = rt.registry
         return holder["state"]
+
+    def _step(self, i: int, step_fn, holder: Dict[str, Any],
+              durations: List[float], reg, histograms: bool) -> None:
+        """One step's body: ``train.input``, ``train.h2d``,
+        ``train.dispatch``, ``train.wait`` and ``train.record`` spans
+        (``train.ckpt`` when a save runs) while the profiler traces."""
+        tc = self.tc
+        t0 = time.perf_counter()
+        with spans.span("train.input"):
+            batch = self.data.get(i)
+        with spans.span("train.h2d"):
+            batch = {k: jax.numpy.asarray(v) for k, v in batch.items()}
+        with spans.span("train.dispatch"), use_mesh(self.mesh):
+            out = step_fn(holder["state"], batch)
+        # the clock stops when the step's results exist, not when the
+        # step was enqueued: step_time and the watchdog see the device
+        with spans.span("train.wait"):
+            holder["state"], metrics = jax.block_until_ready(out)
+        dt = time.perf_counter() - t0
+        with spans.span("train.record"):
+            durations.append(dt)
+            med = float(np.median(durations))
+            if len(durations) > 5 and dt > tc.straggler_factor * med:
+                self.straggler_steps.append(i)
+            m = {k: float(v) for k, v in metrics.items()}
+            m["step"] = i
+            m["step_time"] = dt
+            self.history.append(m)
+            # stamp step metrics into the monitoring registry: the train.*
+            # namespace is live alongside the runtime's io.*/spill.*
+            # gauges, which is what an elastic supervisor would watch
+            reg.set("train.step", float(i))
+            reg.set("train.loss", m.get("loss", 0.0))
+            reg.set("train.step_time_s", dt)
+            reg.inc("train.steps")
+            if histograms:
+                reg.histogram("train.step_wall_s").observe(dt)
+        if tc.ckpt_every and tc.ckpt_dir and (i + 1) % tc.ckpt_every == 0:
+            with spans.span("train.ckpt"):
+                self._save(holder["state"], i + 1)
+
+    def _save(self, state, step: int) -> None:
+        # checkpoint hangs off this step's event; §5 chunked write,
+        # §3 issue-now/resolve-later.  async_ckpt snapshots at issue
+        # time and overlaps inside the runtime's IO queue (virtual
+        # time); the call itself completes before the next step runs.
+        # Under a mesh the NamedShardings ride along and ckpt.save takes
+        # the §6 sharded path: each node writes exactly its own byte
+        # ranges, no host-side gather.
+        tc = self.tc
+        if self.mesh is not None:
+            with use_mesh(self.mesh):
+                if tc.async_ckpt:
+                    self._ckpt_threads.append(ckpt.async_save(
+                        tc.ckpt_dir, state, step))
+                else:
+                    ckpt.save(tc.ckpt_dir, state, step)
+        else:
+            host = jax.tree_util.tree_map(np.asarray, state)
+            if tc.async_ckpt:
+                self._ckpt_threads.append(
+                    ckpt.async_save(tc.ckpt_dir, host, step))
+            else:
+                ckpt.save(tc.ckpt_dir, host, step)

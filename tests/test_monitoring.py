@@ -145,6 +145,37 @@ def test_serve_bench_bit_identical_with_monitoring():
     assert cont["p99_hist_latency_s"] > 0.0
 
 
+def test_virtual_snapshots_bit_identical_while_profiling(tmp_path):
+    """Wall-clock spans record while a JAX profiler trace runs; they touch
+    no virtual clock, so bench_serve and bench_contention read the
+    committed snapshots exactly with recording on."""
+    import jax
+    from benchmarks.bench_contention import _contend
+    from benchmarks.bench_serve import _LOADS, _head_to_head
+    from repro.monitoring import spans
+    spans.clear()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        stats, _wall = _contend(256)
+        cont, stat = _head_to_head(*_LOADS[0][1:])
+    finally:
+        jax.profiler.stop_trace()
+    names = {s.name for s in spans.recorded()}
+    assert {"ocr.run", "serve.iteration", "serve.decode"} <= names
+    want = _snapshot("contention")
+    assert stats.makespan == want["makespan"]
+    assert stats.messages_sent == want["messages_sent"]
+    assert stats.waiter_wakeups == want["waiter_wakeups"]
+    want = _snapshot("serve")
+    assert cont["makespan_s"] == want["makespan_continuous"]
+    assert cont["tok_per_s"] == want["tok_per_s_continuous"]
+    assert cont["p99_latency_s"] == want["p99_latency_s_continuous"]
+    assert stat["p99_latency_s"] == want["p99_latency_s_static"]
+    assert cont["creator_calls"] == want["creator_calls"]
+    assert cont["p99_hist_latency_s"] == want["p99_hist_latency_s_continuous"]
+    assert cont["p99_hist_ttft_s"] == want["p99_hist_ttft_s_continuous"]
+
+
 # ------------------------------------------------- EDT-class histograms
 
 
