@@ -230,62 +230,101 @@ def decode_update_and_attend(q: jax.Array, k_new: jax.Array,
 
 # ------------------------------------------------------------- paged decode
 
+def _write_tokens(pools: jax.Array, layer, phys: jax.Array, slot: jax.Array,
+                  new: jax.Array) -> jax.Array:
+    """Write each row's token (B, 1, KH, hd) at pool page ``phys`` slot
+    ``slot`` of ``layer``; a page past the pool drops.  A pool row holds
+    ``r`` tokens, so the row is read, the token put in its place and the
+    whole row written back: a scatter of whole rows, which updates the
+    pool in place in one pass (a window at a lane offset would instead
+    be expanded into a loop of one-row writes).  With one token to a row
+    the read is dead and the compiler drops it."""
+    b, row_shape = new.shape[0], pools.shape[3:]
+    tw = new.shape[2] * new.shape[3]
+    r = int(np.prod(row_shape)) // tw
+    row = pools[layer, phys, slot // r].reshape(b, r, tw)
+    mine = (jnp.arange(r)[None] == (slot % r)[:, None])[..., None]
+    row = jnp.where(mine, new.reshape(b, 1, tw).astype(pools.dtype), row)
+    return pools.at[layer, phys, slot // r].set(row.reshape(b, *row_shape),
+                                                mode="drop")
+
+
 def paged_update_and_attend(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
-                            k_pages: jax.Array, v_pages: jax.Array,
+                            k_pools: jax.Array, v_pools: jax.Array, layer,
                             page_table: jax.Array, cur_lens: jax.Array,
                             active: jax.Array, *, window: int = 0):
     """Per-request paged decode over §6 pages of a shared cache pool.
 
-    q, k_new, v_new: (B, 1, H|KH, hd); pools (P, KH, page, hd) — every
-    request's KV lives in fixed-size pages of one pool, indexed through
-    ``page_table`` (B, max_pages) int32 (entries past a row's page count
-    are ignored).  ``cur_lens`` (B,) int32 tokens already cached per row;
-    ``active`` (B,) bool — inactive rows write nothing and output zeros.
+    q, k_new, v_new: (B, 1, H|KH, hd); pools (L, P, page // r, *row) —
+    every layer's KV lives in fixed-size pages of one pool, each page
+    ``page`` tokens stored ``r`` to a row, a row being (KH, hd) or
+    (r·KH·hd,) as ``repro.serve.steps.pool_shape`` picks from the shapes,
+    indexed through ``page_table`` (B, max_pages) int32 (entries past a
+    row's page count are ignored).  ``layer`` is the
+    (traced) layer whose pages are read and written; ``cur_lens`` (B,)
+    int32 tokens already cached per row; ``active`` (B,) bool — inactive
+    rows write nothing and output zeros.
 
-    The attention is the lse-combine math of the cache-stripe decode path
-    applied per page: each page contributes a partial max/sum, merged
-    through a global max — numerically identical to one masked softmax
-    over the row's gathered pages.  Returns (out (B,1,H,hd_v), k_pages',
-    v_pages').
+    The whole pools are taken and returned so that a caller carrying them
+    through its layer loop updates them in place: the only pool bytes
+    written are the new tokens', the only ones read the pages gathered.
+
+    The attention is one masked softmax over each row's gathered pages,
+    in f32, with the masking and window of the cache-stripe decode path.
+    Returns (out (B,1,H,hd), k_pools', v_pools').
     """
     b, _, h, hd = q.shape
-    npages, kh, page, _ = k_pages.shape
+    kh = k_new.shape[2]
+    npages, rows_pp = k_pools.shape[1:3]
+    r = int(np.prod(k_pools.shape[3:])) // (kh * hd)  # tokens per pool row
+    page = rows_pp * r
     g = h // kh
-    max_pages = page_table.shape[1]
     scale = 1.0 / np.sqrt(hd)
     cur = jnp.asarray(cur_lens, jnp.int32)
-    rows = jnp.arange(b)
 
     # scatter the new token: row i writes page_table[i, cur//page] slot
     # cur%page; inactive rows aim past the pool and drop
-    phys = page_table[rows, cur // page]
+    phys = page_table[jnp.arange(b), cur // page]
     phys = jnp.where(active, phys, npages)
     slot = cur % page
-    kn = k_new[:, 0].astype(k_pages.dtype)          # (B, KH, hd)
-    vn = v_new[:, 0].astype(v_pages.dtype)
-    k_pages = k_pages.at[phys, :, slot].set(kn, mode="drop")
-    v_pages = v_pages.at[phys, :, slot].set(vn, mode="drop")
+    k_pools = _write_tokens(k_pools, layer, phys, slot, k_new)
+    v_pools = _write_tokens(v_pools, layer, phys, slot, v_new)
 
-    # gather each row's page list and lse-combine across pages
-    kg = k_pages[page_table].astype(jnp.float32)    # (B, mp, KH, page, hd)
-    vg = v_pages[page_table].astype(jnp.float32)
-    qg = q[:, 0].reshape(b, kh, g, hd).astype(jnp.float32)
-    s = jnp.einsum("bkgh,bpksh->bkgps", qg, kg) * scale
-    pos = (jnp.arange(max_pages)[:, None] * page
-           + jnp.arange(page)[None, :])             # (mp, page)
-    valid = pos[None] < (cur + 1)[:, None, None]
+    # gather each row's pages straight from the pool as (B, n, KR, WR):
+    # n pool rows of KR heads of WR lanes, a lane row holding r tokens of
+    # hr heads.  The query is spread block-diagonally over a lane row, so
+    # scores and weighted values are matmuls over the lanes as the pool
+    # stores them (bf16; the cast to f32 fuses in): nothing is laid out
+    # again, whatever the pool's layout.
+    row = k_pools.shape[3:]
+    kr, wr = (row[0] if len(row) == 2 else 1), row[-1]
+    hr = kh // kr
+    kg = k_pools[layer, page_table].reshape(b, -1, kr, wr)
+    vg = v_pools[layer, page_table].reshape(b, -1, kr, wr)
+    n = kg.shape[1]
+    eye_r = jnp.eye(r, dtype=jnp.float32)
+    eye_h = jnp.eye(hr, dtype=jnp.float32)
+    qg = q[:, 0].reshape(b, kr, hr, g, hd).astype(jnp.float32)
+    qd = jnp.einsum("bjigh,ts,iu->bjtihsug", qg, eye_r, eye_h).reshape(
+        b, kr, wr, r * hr * g)
+    s = jnp.einsum("bjwc,bnjw->bjcn", qd, kg.astype(jnp.float32)) * scale
+    s = s.reshape(b, kr, r, hr, g, n)
+    pos = jnp.arange(n)[None, :] * r + jnp.arange(r)[:, None]    # (r, n)
+    valid = pos[None] < (cur + 1)[:, None, None]                 # (B, r, n)
     if window > 0:
         valid &= pos[None] >= jnp.maximum(cur + 1 - window, 0)[:, None, None]
-    s = jnp.where(valid[:, None, None], s, NEG_INF)
-    m_loc = jnp.max(s, axis=-1)                     # (B, KH, g, mp)
-    m_all = jnp.max(m_loc, axis=-1)                 # (B, KH, g)
-    p = jnp.exp(s - m_all[..., None, None])
-    p = jnp.where(valid[:, None, None], p, 0.0)
-    num = jnp.einsum("bkgps,bpksh->bkgh", p, vg)
-    den = jnp.sum(p, axis=(-2, -1))
+    valid = valid[:, None, :, None, None, :]
+    s = jnp.where(valid, s, NEG_INF)
+    m_all = jnp.max(s, axis=(2, 5), keepdims=True)
+    p = jnp.where(valid, jnp.exp(s - m_all), 0.0)
+    den = jnp.sum(p, axis=(2, 5))                                # (B, KR, hr, g)
+    pv = jnp.einsum("bjcn,bnjw->bjcw", p.reshape(b, kr, -1, n),
+                    vg.astype(jnp.float32))
+    num = jnp.einsum("bjtigsuh,ts,iu->bjigh",
+                     pv.reshape(b, kr, r, hr, g, r, hr, hd), eye_r, eye_h)
     out = num / jnp.maximum(den, 1e-37)[..., None]
-    out = out * active[:, None, None, None]
-    return (out.reshape(b, 1, h, -1).astype(q.dtype), k_pages, v_pages)
+    out = out * active[:, None, None, None, None]
+    return (out.reshape(b, 1, h, -1).astype(q.dtype), k_pools, v_pools)
 
 
 # ---------------------------------------------------------------- MLA decode

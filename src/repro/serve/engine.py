@@ -126,6 +126,9 @@ class SyntheticBackend:
 class ModelBackend:
     """Real paged jax serving: per-layer page pools plus the jitted
     prefill-into-pages / paged-decode steps from ``repro.serve.steps``.
+    The steps take the pools donated and update them in place, so every
+    call rebinds ``k_pools`` / ``v_pools`` to its result, and nothing may
+    keep a reference to a pool across a call.
 
     Each call is two spans while the profiler traces: ``serve.dispatch``
     from entry to the jitted call's return (host arrays, argument
@@ -139,7 +142,7 @@ class ModelBackend:
         import jax.numpy as jnp
         from repro.models.layers import _dtype
         from repro.serve.steps import (make_paged_decode_step,
-                                       make_paged_prefill_step)
+                                       make_paged_prefill_step, pool_shape)
         cfg = model.cfg
         if prompt_pad % page_size:
             raise ValueError("prompt_pad must be a multiple of page_size")
@@ -148,8 +151,8 @@ class ModelBackend:
         self.pool_pages = pool_pages
         self.prompt_pad = prompt_pad
         dt = _dtype(cfg.dtype)
-        shape = (cfg.num_layers, pool_pages, cfg.num_kv_heads, page_size,
-                 cfg.head_dim)
+        shape = pool_shape(cfg.num_layers, pool_pages, page_size,
+                           cfg.num_kv_heads, cfg.head_dim)
         self.k_pools = jnp.zeros(shape, dt)
         self.v_pools = jnp.zeros(shape, dt)
         self._np_dtype = np.asarray(jnp.zeros((), dt)).dtype

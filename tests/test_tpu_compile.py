@@ -11,6 +11,8 @@ process may load the TPU library, and every test worker imports this file.
 """
 import os
 
+import numpy as np
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -112,3 +114,74 @@ def test_ssd_scan(one_chip):
         _spec(one_chip, (h,), jnp.float32),
         _spec(one_chip, (b, s, n), jnp.bfloat16),
         _spec(one_chip, (b, s, n), jnp.bfloat16))
+
+
+# (registry model, pool pages, page, b_cap, max_pages, prompt_pad): the
+# serve benchmark cells' shapes, with 2 layers to keep the compile short
+@pytest.mark.parametrize("name,pool,page,b_cap,max_pages,pad", [
+    ("smollm-360m", 3072, 16, 32, 128, 1536),        # 5 KV heads of 64
+    ("h2o-danube-3-4b", 1800, 16, 11, 192, 2048)])   # 8 KV heads of 120
+def test_paged_steps_update_pools_in_place(one_chip, name, pool, page, b_cap,
+                                           max_pages, pad):
+    """Both paged steps alias the donated pools, and nothing of a pool's or
+    a pool layer's size is copied, sliced or stacked: the only such
+    instructions are the loop's own plumbing and the in-place scatters of
+    the new tokens or pages.  The decode's gathered pages are never laid
+    out again either."""
+    import dataclasses
+    import re
+
+    from repro.configs import get_config
+    from repro.models.model import LanguageModel
+    from repro.serve.steps import (make_paged_decode_step,
+                                   make_paged_prefill_step, pool_shape)
+
+    cfg = dataclasses.replace(get_config(name), num_layers=2,
+                              dtype="bfloat16", param_dtype="bfloat16")
+    model = LanguageModel(cfg)
+    params = jax.tree_util.tree_map(
+        lambda a: _spec(one_chip, a.shape, a.dtype),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    shape = pool_shape(2, pool, page, cfg.num_kv_heads, cfg.head_dim)
+    kv = _spec(one_chip, shape, jnp.bfloat16)
+    i32 = _spec(one_chip, (b_cap,), jnp.int32)
+    programs = {
+        "decode": make_paged_decode_step(model).lower(
+            params, kv, kv, _spec(one_chip, (b_cap, max_pages), jnp.int32),
+            i32, _spec(one_chip, (b_cap,), jnp.bool_), i32).compile(),
+        "prefill": make_paged_prefill_step(model, page).lower(
+            params, kv, kv, _spec(one_chip, (1, pad), jnp.int32),
+            _spec(one_chip, (), jnp.int32),
+            _spec(one_chip, (pad // page,), jnp.int32)).compile()}
+
+    pool_elems = 2 * pool * cfg.num_kv_heads * page * cfg.head_dim
+    pool_sized = {pool_elems, pool_elems // 2}
+    gathered = b_cap * max_pages * page * cfg.num_kv_heads * cfg.head_dim
+    plumbing = {"parameter", "get-tuple-element", "tuple", "bitcast", "while"}
+    for kind, compiled in programs.items():
+        assert compiled.memory_analysis().alias_size_in_bytes >= \
+            2 * pool_elems * 2, kind
+        body, roots, comp = [], {}, None
+        for line in compiled.as_text().splitlines():
+            if not line.startswith(" "):
+                comp = line.split(" ")[0]
+                continue
+            m = re.match(r"\s*(ROOT )?%[\w.\-]+ = (\w+)\[([\d,]*)\]\S* "
+                         r"([\w\-]+)\(", line)
+            if m:
+                if m.group(1):
+                    roots[comp] = m.group(4)
+                body.append((comp, m.group(2), m.group(3), m.group(4), line))
+        fused = set(re.findall(r"calls=(%[\w.\-]+)", compiled.as_text()))
+        for comp, dtype, dims, op, line in body:
+            n = int(np.prod([int(d) for d in dims.split(",") if d]))
+            if comp in fused or dtype != "bf16":
+                continue
+            assert not (n == gathered and op == "copy"), (kind, line)
+            if n not in pool_sized:
+                continue
+            if op == "fusion":
+                called = re.search(r"calls=(%[\w.\-]+)", line).group(1)
+                assert roots[called] == "scatter", (kind, line)
+            else:
+                assert op in plumbing, (kind, line)
